@@ -3,9 +3,9 @@
 Reads benchmarks/results/dryrun/*.json (written by repro.launch.dryrun) and
 derives, per (arch x shape x mesh):
 
-  compute term    = HLO_FLOPs_per_device / PEAK_FLOPS
-  memory term     = HLO_bytes_per_device / HBM_BW
-  collective term = collective_bytes_per_device / ICI_BW
+  compute term    = HLO_FLOPs_per_device / peak FLOP/s
+  memory term     = HLO_bytes_per_device / peak HBM bytes/s
+  collective term = collective_bytes_per_device / ICI bytes/s per link
 
 (cost_analysis and the SPMD HLO are per-partition, i.e. per-chip, so the
 "/ chips" in the spec is already applied.)  Also reports MODEL_FLOPS =
@@ -13,6 +13,10 @@ derives, per (arch x shape x mesh):
 term with a one-line remedy suggestion.
 
   PYTHONPATH=src python -m benchmarks.roofline [--results DIR] [--md FILE]
+
+The peaks come from `PEAKS`, keyed by the chip's ``device_kind`` as JAX
+reports it (a record's own ``device_kind``, else the v5e the dry-run
+models); a kind missing from the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -24,10 +28,32 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# TPU v5e per chip
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9       # bytes/s
-ICI_BW = 50e9        # bytes/s/link
+# Per-chip peaks keyed by `jax.devices()[i].device_kind`.
+PEAKS = {
+    "TPU v5 lite": {
+        "flops": 197e12,  # bf16 FLOP/s
+        "hbm_bytes_per_s": 819e9,
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 ICI links
+        "ici_bytes_per_s_per_link": 50e9,
+        "source": "Google Cloud documentation, 'TPU v5e' system "
+                  "architecture: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, "
+                  "1,600 Gbit/s ICI per chip",
+    },
+}
+
+# The dry-run sweep (repro.launch.dryrun) models a v5e pod.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """The `PEAKS` row for ``device_kind``; raises on a chip not in the
+    table, since another chip's peaks would make every share wrong."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (add a row with its source)") from None
 
 REMEDY = {
     "compute": "raise arithmetic intensity: larger per-chip tiles / fewer remat recomputes",
@@ -57,13 +83,15 @@ def load_results(results_dir: str) -> list[dict]:
 
 
 def analyze(rec: dict) -> dict:
+    device_kind = rec.get("device_kind", DRYRUN_DEVICE_KIND)
+    peak = peaks(device_kind)
     flops = (rec.get("flops_per_device") or 0.0) + _slstm_correction(
         rec["arch"], rec["kind"], rec["tokens"], rec["chips"])
     mem_bytes = rec.get("bytes_per_device") or 0.0
     coll_bytes = rec.get("collectives", {}).get("total_bytes", 0)
-    t_compute = flops / PEAK_FLOPS
-    t_memory = mem_bytes / HBM_BW
-    t_coll = coll_bytes / ICI_BW
+    t_compute = flops / peak["flops"]
+    t_memory = mem_bytes / peak["hbm_bytes_per_s"]
+    t_coll = coll_bytes / peak["ici_bytes_per_s_per_link"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     # model flops: 6*N*D for train (fwd+bwd); 2*N*D for inference
@@ -72,10 +100,11 @@ def analyze(rec: dict) -> dict:
     model_flops = mult * n * rec["tokens"] / rec["chips"]
     ratio = model_flops / flops if flops else 0.0
     bound = max(terms.values())
-    frac_of_roofline = (model_flops / PEAK_FLOPS) / bound if bound else 0.0
+    frac_of_roofline = (model_flops / peak["flops"]) / bound if bound else 0.0
     return {
         **{k: rec.get(k) for k in ("arch", "shape", "mesh", "kind", "chips",
                                    "gossip")},
+        "device_kind": device_kind,
         "t_compute_s": t_compute,
         "t_memory_s": t_memory,
         "t_collective_s": t_coll,

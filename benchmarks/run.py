@@ -60,9 +60,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
+
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 ROWS = []
+
+
+def _cpu_child_env() -> dict:
+    """Environment for a benchmark child that measures host-side work
+    (fake-device meshes, socket transports): pinned to the CPU, so it
+    never competes with this process for a chip this process may hold."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    return env
 
 
 def _write_bench_json(update: dict):
@@ -957,12 +970,8 @@ def bench_multihost(steps=8, agents=4):
                    "--per-agent-batch", "2", "--seq-len", "16",
                    "--seed", "0", "--checkpoint-dir", root,
                    "--checkpoint-every", str(steps), "--timeout", "120"]
-            env = dict(os.environ)
-            env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
-                os.pathsep + env["PYTHONPATH"]
-                if env.get("PYTHONPATH") else "")
             out = subprocess.run(cmd, capture_output=True, text=True,
-                                 timeout=600, env=env)
+                                 timeout=600, env=_cpu_child_env())
             if out.returncode != 0:
                 raise RuntimeError(f"multihost world={world} failed:\n"
                                    + out.stderr[-2000:])
@@ -1163,7 +1172,7 @@ def bench_overlap(steps=30, ring_cols=65536, sock_steps=40,
                     [sys.executable, script, src_dir, str(r), mode, str(p0),
                      str(p1), str(sock_steps), str(agents), str(sock_dim)],
                     stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                    text=True))
+                    text=True, env=_cpu_child_env()))
                 time.sleep(0.3)
             outs = []
             for p in procs:
@@ -1335,7 +1344,8 @@ def bench_sharded_lm(steps=4, agents=2, fsdp=2):
     script = _SHARDED_LM_SCRIPT.format(src=src, agents=agents, fsdp=fsdp,
                                        steps=steps)
     out = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=1200)
+                         capture_output=True, text=True, timeout=1200,
+                         env=_cpu_child_env())
     if out.returncode != 0:
         raise RuntimeError("bench_sharded_lm subprocess failed:\n"
                            + out.stderr[-3000:])
@@ -1553,6 +1563,7 @@ def main(argv=None) -> None:
                    help="run a single benchmark (substring match on "
                         + ", ".join(BENCHES))
     args = p.parse_args(argv)
+    use_compile_cache()
     if args.only:
         selected = {k: v for k, v in BENCHES.items() if args.only in k}
         if not selected:

@@ -38,6 +38,7 @@ import time as _time
 import weakref
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
 from ..data import worker as _w
@@ -222,7 +223,11 @@ def _writer_loop(directory: str, q: queue.Queue, state: _WriterState,
 def _subprocess_commit_loop(directory: str, keep_last: int | None,
                             keep_every: int | None, completed0: list[int],
                             jobq, ackq) -> None:
-    """Child-process main: commit jobs until the None sentinel."""
+    """Child-process main: commit jobs until the None sentinel.  The jobs
+    are plain numpy, so no JAX backend is ever initialised here; the
+    CPU pin makes sure a future change cannot make this child claim the
+    accelerator the parent process holds."""
+    jax.config.update("jax_platforms", "cpu")
     state = _WriterState(completed0)
     while True:
         job = jobq.get()

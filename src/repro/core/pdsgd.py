@@ -121,6 +121,16 @@ def _per_agent_bits(key: jax.Array, step: jax.Array, grads: Pytree) -> Pytree:
     return jax.vmap(bits_one_agent)(keys, grads)
 
 
+def krng_seed(key: jax.Array, step: jax.Array) -> jax.Array:
+    """The (2,) uint32 seed of the in-kernel Lambda draw at ``step``: the
+    step index, then 32 bits from the step's Lambda key.  The kernels fold
+    each tile's coordinates into these words (`kernels.obfuscate.
+    tile_seed`), so Lambda is fresh at every iteration by construction."""
+    bits = jax.random.bits(agent_key(jax.random.fold_in(key, 1), step, 0),
+                           (), jnp.uint32)
+    return jnp.stack([jnp.asarray(step).astype(jnp.uint32), bits])
+
+
 def pdsgd_update(
     params: Pytree,
     grads: Pytree,
@@ -234,9 +244,7 @@ def pdsgd_update(
         perms = C.perm_stack(n_data, n_pod)
         bits = seed = None
         if runtime.resolve_kernel_rng(kernel_rng):
-            seed = jax.random.bits(
-                agent_key(jax.random.fold_in(key, 1), step, 0), (2,),
-                jnp.uint32)
+            seed = krng_seed(key, step)
         else:
             bits = _per_agent_bits(jax.random.fold_in(key, 1), step, grads)
         out = ring_pdsgd_tree(w_tab, b_rows, perms, params, grads, bits,
@@ -274,9 +282,7 @@ def pdsgd_update(
         if runtime.resolve_kernel_rng(kernel_rng):
             # seed the TPU PRNG from the same per-step Lambda key the HBM
             # bits would have been drawn from; no bits staging at all
-            seed = jax.random.bits(
-                agent_key(jax.random.fold_in(key, 1), step, 0), (2,),
-                jnp.uint32)
+            seed = krng_seed(key, step)
         else:
             bits = _per_agent_bits(jax.random.fold_in(key, 1), step, grads)
         out = fused_pdsgd_tree(W, B, params, grads, bits, lam_bar,

@@ -26,7 +26,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = [
@@ -447,11 +446,11 @@ def torus_gossip_pdsgd(mesh, params: Pytree, u: Pytree, b: jax.Array, *,
         return out
 
     out_specs = (leaf_spec, P(agent_spec)) if capture else leaf_spec
-    result = shard_map(
+    result = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(agent_spec), P(agent_spec), leaf_spec, leaf_spec),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(b, w_tab, params, u)
     if not capture:
         return result

@@ -272,7 +272,6 @@ class ShardMapTransport(Transport):
     def _make_body(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         dirs, n_data, n_pod = self._dirs, self.n_data, self.n_pod
@@ -303,9 +302,9 @@ class ShardMapTransport(Transport):
                 acc = acc + stack[order[r]]
             return acc
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self.mesh, in_specs=(P(spec), P(spec)),
-            out_specs=P(spec), check_rep=False))
+            out_specs=P(spec), check_vma=False))
 
     def exchange(self, x_local, u_local, W, B, *, step: int = 0,
                  capture: bool = False):

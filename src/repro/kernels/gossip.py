@@ -45,9 +45,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .obfuscate import tile_seed
 from .runtime import resolve_interpret
 
 DEFAULT_BLOCK_N = 512
+# f32 matmuls at full precision: on the TPU's MXU the default rounds f32
+# operands to bf16, which would round the mixing weights (W's rows then no
+# longer sum to 1) and every staged v_j the 0/1 shift matmul moves.  The
+# (m, m) operands make the extra MXU passes free next to the HBM traffic.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _gossip_kernel(w_ref, b_ref, x_ref, u_ref, o_ref):
@@ -55,8 +61,8 @@ def _gossip_kernel(w_ref, b_ref, x_ref, u_ref, o_ref):
     b = b_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
-    mixed = jnp.dot(w, x, preferred_element_type=jnp.float32)
-    desc = jnp.dot(b, u, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(w, x, precision=_EXACT, preferred_element_type=jnp.float32)
+    desc = jnp.dot(b, u, precision=_EXACT, preferred_element_type=jnp.float32)
     o_ref[...] = (mixed - desc).astype(o_ref.dtype)
 
 
@@ -126,8 +132,8 @@ def _masked_gossip_kernel(mask_ref, b_ref, x_ref, u_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
     w = _metropolis_weights(mask)
-    mixed = jnp.dot(w, x, preferred_element_type=jnp.float32)
-    desc = jnp.dot(b, u, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(w, x, precision=_EXACT, preferred_element_type=jnp.float32)
+    desc = jnp.dot(b, u, precision=_EXACT, preferred_element_type=jnp.float32)
     o_ref[...] = (mixed - desc).astype(o_ref.dtype)
 
 
@@ -188,8 +194,8 @@ def _masked_gossip_krng_kernel(seed_ref, prob_ref, adj_ref, b_ref, x_ref,
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
     w = _metropolis_weights(mask)
-    mixed = jnp.dot(w, x, preferred_element_type=jnp.float32)
-    desc = jnp.dot(b, u, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(w, x, precision=_EXACT, preferred_element_type=jnp.float32)
+    desc = jnp.dot(b, u, precision=_EXACT, preferred_element_type=jnp.float32)
     o_ref[...] = (mixed - desc).astype(o_ref.dtype)
 
 
@@ -359,7 +365,7 @@ def _ring_accumulate(w, b, perm, x, u, o_ref, v_ref, stage_ref, *, ndirs,
         # 0/1 permutation matmul == the ppermute shift, bit-exact for
         # finite v (each output row selects exactly one staged row)
         acc = acc + jax.lax.dot_general(
-            perm[d], v, (((1,), (0,)), ((), ())),
+            perm[d], v, (((1,), (0,)), ((), ())), precision=_EXACT,
             preferred_element_type=jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
@@ -528,13 +534,13 @@ def _ring_obfuscate_krng_kernel(w_ref, b_ref, perm_ref, x_ref, g_ref,
                                 seed_ref, scal_ref, o_ref, bits_ref,
                                 *refs, ndirs, capture):
     """`_ring_obfuscate_kernel` with the Λ bits drawn in-VMEM by the TPU
-    PRNG — re-seeded (seed0, seed1, tile) per column tile so the stream
-    is grid-order independent, exported via ``bits_ref`` for replay
-    parity through the HBM-bits kernel (the `obfuscate_update_krng`
-    contract)."""
+    PRNG — re-seeded per column tile from `obfuscate.tile_seed` (the
+    tile index folded into the random word) so the
+    stream is grid-order independent, exported via ``bits_ref`` for
+    replay parity through the HBM-bits kernel (the
+    `obfuscate_update_krng` contract)."""
     stage_ref = refs[-1]
-    i = pl.program_id(0)
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], i)
+    pltpu.prng_seed(*tile_seed(seed_ref, 0, pl.program_id(0)))
     bits = pltpu.bitcast(pltpu.prng_random_bits(o_ref.shape), jnp.uint32)
     bits_ref[...] = bits
     x = x_ref[...].astype(jnp.float32)
@@ -560,8 +566,9 @@ def ring_obfuscate_gossip_krng(w_tab: jax.Array, b_tab: jax.Array,
                                interpret: bool | None = None):
     """TPU-only fused ring step with in-VMEM Λ randomness.
 
-    ``seed``: (2,) uint32/int32 PRNG words (derive from the step's Λ
-    key).  Returns ``(out, bits)`` — or ``(out, bits, v, u)`` with
+    ``seed``: (2,) uint32/int32 PRNG words: the step index, then random
+    bits from the step's Λ key (`core.pdsgd.krng_seed`).  Returns
+    ``(out, bits)`` — or ``(out, bits, v, u)`` with
     ``capture=True`` — where ``bits`` is the uint32 draw the kernel
     used; feed it back through `ring_obfuscate_gossip` to pin the two
     randomness paths bit-for-bit.  Raises at lowering on non-TPU

@@ -11,8 +11,8 @@ op that runs on every parameter, every step (d up to 34B here vs the
 paper's 1.7M).  Tiles are (8k, 128)-aligned for the VPU lanes.
 
 On a real TPU the `bits` input disappears: `obfuscate_update_krng` seeds
-the per-core PRNG (`pltpu.prng_seed`, re-seeded per grid tile so tiles
-stay order-independent) and draws the bits in-VMEM with
+the per-core PRNG (`pltpu.prng_seed`, re-seeded per grid tile from
+`tile_seed` so tiles stay order-independent) and draws the bits in-VMEM with
 `pltpu.prng_random_bits` — zero HBM traffic for lambda, behind the
 `runtime.default_kernel_rng` knob.  The variant also WRITES the bits it
 drew as a second output, so the parity test can replay them through the
@@ -101,17 +101,39 @@ def _obfuscate_update(x, g, bits, lam_bar, w_self, b_self,
 # In-kernel TPU randomness (runtime.default_kernel_rng path)
 # ---------------------------------------------------------------------------
 
+# Odd 32-bit multipliers (golden-ratio and murmur3 constants, as int32):
+# multiplying by an odd number is a bijection mod 2**32, so distinct tile
+# coordinates always give distinct seed words.
+_TILE_MIX_I = -1640531527  # 0x9E3779B9
+_TILE_MIX_J = -2048144789  # 0x85EBCA6B
+
+
+def tile_seed(seed_ref, i, j):
+    """The two PRNG seed words for grid tile (i, j): the call's (seed0,
+    seed1) with ``i`` folded into the first word and ``j`` into the
+    second.  Mosaic seeds the TPU PRNG with at most two words, so the
+    coordinates cannot be passed as extra words; folding them keeps each
+    tile's stream a function of (seed, i, j) alone, never of the order in
+    which the grid runs.
+
+    Callers put the step index in seed0 and random bits in seed1
+    (`core.pdsgd.krng_seed`).  With one row of tiles, as on every fused
+    path (i = 0), word 0 is then the step itself: no two steps of a run
+    (below 2**32 steps) and no two tiles of a step share a stream, by
+    construction rather than by chance."""
+    return seed_ref[0] ^ (i * _TILE_MIX_I), seed_ref[1] ^ (j * _TILE_MIX_J)
+
+
 def _obfuscate_krng_kernel(x_ref, g_ref, seed_ref, scal_ref, o_ref, bits_ref):
     """Same math as `_obfuscate_kernel`, but the uint32 draws come from the
-    per-core TPU PRNG instead of an HBM input.  The PRNG is re-seeded with
-    (seed0, seed1, i, j) at every tile so the stream a tile sees depends
-    only on its grid coordinates, never on grid iteration order.  The bits
-    are also written out so the HBM-input kernel can replay them (parity
-    test) and so the eager Lambda-audit path can reconstruct lambda."""
+    per-core TPU PRNG instead of an HBM input.  The PRNG is re-seeded at
+    every tile from `tile_seed`, so the stream a tile sees depends only on
+    the step seed and its grid coordinates, never on grid iteration
+    order.  The bits are also written out so the HBM-input kernel can
+    replay them (parity test) and so the eager Lambda-audit path can
+    reconstruct lambda."""
     from jax.experimental.pallas import tpu as pltpu
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], i, j)
+    pltpu.prng_seed(*tile_seed(seed_ref, pl.program_id(0), pl.program_id(1)))
     bits = pltpu.bitcast(pltpu.prng_random_bits(o_ref.shape), jnp.uint32)
     bits_ref[...] = bits
     o_ref[...] = _obfuscate_math(x_ref[...], g_ref[...], bits,
@@ -125,8 +147,9 @@ def obfuscate_update_krng(x: jax.Array, g: jax.Array, seed: jax.Array,
                           interpret: bool | None = None):
     """TPU-only obfuscation with in-VMEM randomness.
 
-    ``seed``: (2,) uint32/int32 PRNG seed words (derive from the step's
-    Lambda key, e.g. ``jax.random.bits(key, (2,), jnp.uint32)``).  Returns
+    ``seed``: (2,) uint32/int32 PRNG seed words: the step index, then
+    random bits from the step's Lambda key (`core.pdsgd.krng_seed`; see
+    `tile_seed`).  Returns
     ``(v, bits)`` where ``bits`` is the (R, C) uint32 draw the kernel used
     — feed it back through `obfuscate_update` to cross-validate the two
     randomness paths bit-for-bit.  Raises at lowering on non-TPU backends

@@ -119,7 +119,9 @@ def fused_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
     ``observe=True`` returns ``(out_tree, {"x": (m, D), "u": (m, D)})`` —
     the kernel's OWN flattened state and obfuscated-gradient buffers
     (padding stripped), which the privacy-audit wire-tap layer turns into
-    the v_ij observation tensor.  Emitting the kernel's u (not an eager
+    the v_ij observation tensor.  With the in-kernel draw it also holds
+    ``"bits"``, the (m, D) uint32 draw the kernel made, in the same
+    column order.  Emitting the kernel's u (not an eager
     re-derivation) is what makes the capture an audit of what this path
     actually realized; the buffers already exist, so capture adds no
     kernel work.
@@ -172,8 +174,9 @@ def fused_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
     x_flat, pad = _pad_cols(x_flat, 512)
     g_flat, _ = _pad_cols(g_flat, 512)
     # w_self=0, b_self=-1 turns the self-term kernel into u = lambda ∘ g.
+    bits_flat = None
     if use_krng:
-        u_flat, _ = obfuscate_update_krng(
+        u_flat, bits_flat = obfuscate_update_krng(
             x_flat, g_flat, seed, lam_bar, jnp.float32(0.0),
             jnp.float32(-1.0), block=(x_flat.shape[0], 256),
             interpret=interpret)
@@ -215,6 +218,8 @@ def fused_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
     ncols = sum(sizes)
     flats = {"x": x_flat[:, :ncols].astype(jnp.float32),
              "u": u_flat[:, :ncols].astype(jnp.float32)}
+    if use_krng:
+        flats["bits"] = bits_flat[:, :ncols]
     return out_tree, flats
 
 
@@ -360,7 +365,6 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
     if leaf_specs is None:
         raise ValueError("mesh given but leaf_specs is None; resolve "
                          "specs via dist.sharding.logical_spec")
-    from jax.experimental.shard_map import shard_map
 
     def leaf_obfuscate(x, g, bits, spec):
         def body(xl, gl, bl):
@@ -374,8 +378,8 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
             if pad:
                 u = u[:, :-pad]
             return u.reshape(xl.shape).astype(xl.dtype)
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_rep=False)(x, g, bits)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(x, g, bits)
 
     u_tree = jax.tree.map(leaf_obfuscate, x_tree, g_tree, bits_tree,
                           leaf_specs)

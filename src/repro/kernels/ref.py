@@ -6,6 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# Full-precision f32 contractions, as in the gossip kernels (a TPU's
+# default would round the f32 operands to bf16).
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def bits_to_uniform(bits: jax.Array) -> jax.Array:
     """uint32 -> float32 in [0, 1): set mantissa, subtract 1."""
@@ -27,9 +31,9 @@ def gossip_ref(W: jax.Array, B: jax.Array, X: jax.Array,
                U: jax.Array) -> jax.Array:
     """x' = W @ X - B @ U over the leading agent dim; X/U: (m, n)."""
     out = (jnp.einsum("ij,jn->in", W.astype(jnp.float32),
-                      X.astype(jnp.float32))
+                      X.astype(jnp.float32), precision=_EXACT)
            - jnp.einsum("ij,jn->in", B.astype(jnp.float32),
-                        U.astype(jnp.float32)))
+                        U.astype(jnp.float32), precision=_EXACT))
     return out.astype(X.dtype)
 
 
@@ -51,7 +55,8 @@ def ring_gossip_ref(w_tab: jax.Array, b_tab: jax.Array, perms: jax.Array,
     vs = [w[:, d + 1:d + 2] * x - b[:, d + 1:d + 2] * u
           for d in range(ndirs)]
     for d in range(ndirs):
-        out = out + jnp.einsum("ij,jn->in", perms[d], vs[d])
+        out = out + jnp.einsum("ij,jn->in", perms[d], vs[d],
+                               precision=_EXACT)
     return out.astype(X.dtype), jnp.stack(vs)
 
 

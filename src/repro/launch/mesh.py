@@ -14,6 +14,7 @@ that says what would fit.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
@@ -25,12 +26,22 @@ __all__ = [
 ]
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis `AxisType.Auto`: this repo's steps
+    place arrays with `NamedSharding` and leave propagation to GSPMD
+    (`spmd_axis_name` vmaps, sharding constraints).  Explicit axes (the
+    `jax.make_mesh` default) put the sharding into the array types, which
+    the agent vmap then refuses for inputs placed differently."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 256 chips (16 data x 16 model).  Multi-pod: 2 pods = 512
     chips with a leading "pod" axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_global_mesh(*, model_parallel: int = 1, agents: int | None = None):
@@ -66,7 +77,7 @@ def make_global_mesh(*, model_parallel: int = 1, agents: int | None = None):
     else:
         shape = (slots, model_parallel)
         axes = ("data", "model")
-    mesh = jax.make_mesh(shape, axes, devices=devices)
+    mesh = _auto_mesh(shape, axes, devices=devices)
     if agents is not None:
         validate_agent_tiling(mesh, agents)
     return mesh
@@ -95,8 +106,8 @@ def make_sharded_mesh(*, agents: int | None = None, fsdp: int = 1,
             f"per-agent group fsdp*tensor={group} does not divide the "
             f"{n} visible devices")
     slots = n // group
-    mesh = jax.make_mesh((slots, fsdp, tensor), ("data", "fsdp", "model"),
-                         devices=devices)
+    mesh = _auto_mesh((slots, fsdp, tensor), ("data", "fsdp", "model"),
+                      devices=devices)
     if agents is not None:
         validate_agent_tiling(mesh, agents)
     return mesh

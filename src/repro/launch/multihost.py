@@ -687,6 +687,36 @@ class _Coordinator(threading.Thread):
             pass
 
 
+def rank_platform(env: dict) -> str:
+    """The JAX platform a rank process started with ``env`` would use.
+    Read from ``JAX_PLATFORMS`` when that excludes the TPU; otherwise
+    probed in a short-lived child, so this launcher never loads the TPU
+    runtime itself (a process that has loaded it holds the chips)."""
+    platforms = [p for p in env.get("JAX_PLATFORMS", "").split(",") if p]
+    if platforms and "tpu" not in platforms:
+        return platforms[0]
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        env=env, capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        raise RuntimeError("probing the rank platform failed:\n"
+                           + probe.stderr[-2000:])
+    return probe.stdout.strip().splitlines()[-1]
+
+
+def check_rank_platform(world: int, env: dict) -> None:
+    """Refuse to start more TPU-using rank processes than this host can
+    give a chip each.  A rank process opens every chip it can see, and a
+    chip belongs to one process at a time, so a host runs at most one TPU
+    rank: the others would fail or hang at start-up."""
+    if world > 1 and rank_platform(env) == "tpu":
+        raise ValueError(
+            f"--world {world} would start {world} TPU-using rank processes "
+            "on this host, but the first one holds all of its chips; run "
+            "one rank per host, or put the ranks on the CPU with "
+            "JAX_PLATFORMS=cpu")
+
+
 def launch(args) -> dict:
     """Spawn ``--world`` rank processes, monitor them, merge artifacts.
 
@@ -711,14 +741,15 @@ def launch(args) -> dict:
         print(json.dumps({"multihost_summary": out}), flush=True)
         return out
 
-    coord = _Coordinator(world, args.timeout)
-    coord.start()
-    procs: dict[int, subprocess.Popen] = {}
     env = dict(os.environ)
     src_dir = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = src_dir + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    check_rank_platform(world, env)
+    coord = _Coordinator(world, args.timeout)
+    coord.start()
+    procs: dict[int, subprocess.Popen] = {}
     passthrough = _args_to_argv(args)
     for r in range(world):
         cmd = [sys.executable, "-m", "repro.launch.multihost",

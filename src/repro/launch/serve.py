@@ -38,6 +38,7 @@ from ..models import build_model
 from ..serve import (Request, ServeEngine, init_loop_state, make_decode_loop,
                      sequential_decode)
 from ..serve.engine import Completion
+from .compile_cache import use_compile_cache
 
 
 def _percentile(xs, q):
@@ -235,6 +236,7 @@ def main(argv=None):
     p.add_argument("--parity-check", action="store_true",
                    help="re-decode every request sequentially and compare")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.mode == "auto":

@@ -56,6 +56,7 @@ a finished run resumes from its end, not from the last periodic boundary.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import time
@@ -72,6 +73,7 @@ from ..core import (init_state, make_decentralized_step, make_mixing,
 from ..core.schedules import warmup_harmonic
 from ..data import make_lm_pipeline, make_placer, prefetch_chunks
 from ..models import build_model
+from .compile_cache import use_compile_cache
 from .steps import per_step_keys
 
 
@@ -252,16 +254,27 @@ def build_faults(args):
 
 
 def run_training(args, mesh=None) -> dict:
-    """Run the driver loop; returns {state, history, resumed_from}.
+    """Run the driver loop; returns {state, history, resumed_from, ...}.
 
-    ``history`` is the list of emitted log records.  Factored out of `main`
-    so tests can drive resume round-trips in-process.
+    ``history`` is the list of emitted log records.  ``step`` is the built
+    training step; with ``--unroll-k`` > 1, ``compiled`` is the scanned
+    step program (compiled ahead of its first dispatch) and ``timing``
+    holds its compile seconds apart from the steady wall seconds of the
+    steps that followed, periodic checkpoint saves inside them included,
+    and, with a checkpoint directory, the seconds until the terminal
+    checkpoint is on disk (``compiled`` and ``timing`` are None for the
+    eager loop).  Factored out of
+    `main` so tests can drive resume round-trips in-process.
     """
     cfg = get_config(args.arch)
     if args.scan_layers:
-        import dataclasses
         cfg = dataclasses.replace(cfg, scan_layers=True)
     sharded = args.mesh_fsdp > 1 or args.mesh_tensor > 1
+    if not sharded and jax.device_count() > 1:
+        print(json.dumps({
+            "warning": f"{jax.device_count()} devices visible but no "
+                       "--mesh-fsdp/--mesh-tensor: every agent runs on "
+                       "the default device"}))
     if sharded and mesh is None:
         from .mesh import make_sharded_mesh
         mesh = make_sharded_mesh(agents=args.agents, fsdp=args.mesh_fsdp,
@@ -399,6 +412,11 @@ def run_training(args, mesh=None) -> dict:
     start = 0
     history: list[dict] = []
     t0 = time.time()
+    # Scanned loop only: its one step program is compiled ahead of the
+    # first dispatch so compile time is reported apart from the steady
+    # wall time of the steps that follow it (an eager tail included).
+    compiled = compile_s = t_steady = None
+    steps_timed = 0
 
     # Cumulative fault/sentinel counters (keys exist in aux only when the
     # corresponding layer is configured, so the fault-free loop never pays
@@ -423,10 +441,12 @@ def run_training(args, mesh=None) -> dict:
                     nonf = v
         return nonf
 
-    def log(k, loss, cons):
+    def log(k, loss, cons, step_losses=None):
         rec = {"step": int(k), "loss": float(loss),
                "consensus_error": float(cons),
                "elapsed_s": round(time.time() - t0, 1)}
+        if step_losses is not None:
+            rec["step_losses"] = [float(x) for x in np.asarray(step_losses)]
         if monitor is not None:
             diag = monitor(jnp.asarray(int(k), jnp.int32))
             rec.update(b_window=b_window,
@@ -577,7 +597,14 @@ def run_training(args, mesh=None) -> dict:
                                      depth=args.prefetch_depth) as chunks:
                     for chunk in chunks:
                         keys = per_step_keys(key, k, args.unroll_k)
+                        if compiled is None:
+                            tc = time.perf_counter()
+                            compiled = scanned.lower(state, chunk,
+                                                     keys).compile()
+                            compile_s = time.perf_counter() - tc
+                            t_steady = time.perf_counter()
                         state, aux = scanned(state, chunk, keys)
+                        steps_timed += args.unroll_k
                         k_next = k + args.unroll_k
                         nonf = tally(aux)
                         streak = streak + 1 if nonf else 0
@@ -589,7 +616,8 @@ def run_training(args, mesh=None) -> dict:
                         if (crosses(k, k_next, args.log_every)
                                 or k_next >= args.steps):
                             log(k_next - 1, aux["loss"].mean(),
-                                aux["consensus_error"][-1])
+                                aux["consensus_error"][-1],
+                                step_losses=aux["loss"])
                         if nonf:
                             state, rk, rolled = try_rollback(state)
                             if rolled:
@@ -611,6 +639,7 @@ def run_training(args, mesh=None) -> dict:
             sk = jax.random.fold_in(key, k)
             batch = place(pipeline.batch_at(k))
             state, aux = step(state, batch, sk)
+            steps_timed += 1
             nonf = tally(aux)
             streak = streak + 1 if nonf else 0
             if k % args.log_every == 0 or k == args.steps - 1:
@@ -625,6 +654,13 @@ def run_training(args, mesh=None) -> dict:
                 manager.save(k + 1, state)
             k += 1
 
+        timing = None
+        if compiled is not None:
+            jax.block_until_ready(state)
+            timing = {"compile_s": compile_s,
+                      "steady_s": time.perf_counter() - t_steady,
+                      "steps_timed": steps_timed}
+            print(json.dumps(timing))
         if manager is not None:
             # Terminal checkpoint: a run whose --steps doesn't cross a
             # --checkpoint-every boundary must still resume from its END,
@@ -632,7 +668,14 @@ def run_training(args, mesh=None) -> dict:
             # `save` is idempotent, so a boundary landing exactly on
             # args.steps doesn't write twice; max(start, steps) is what
             # state.step holds even when a resume starts past --steps.
+            tc = time.perf_counter()
             manager.save(max(start, args.steps), state)
+            manager.wait()
+            if timing is not None:
+                # after the step clock stopped: the stall until the last
+                # checkpoint is on disk, reported on its own
+                timing["checkpoint_s"] = time.perf_counter() - tc
+                print(json.dumps({"checkpoint_s": timing["checkpoint_s"]}))
     finally:
         if manager is not None:
             # Drains in-flight writes; re-raises a writer failure so the
@@ -665,11 +708,13 @@ def run_training(args, mesh=None) -> dict:
 
     return {"state": state, "history": history, "resumed_from": start or None,
             "privacy_audit": audit_report, "fault_totals": fault_totals,
-            "rollbacks": rollbacks}
+            "rollbacks": rollbacks, "step": step, "compiled": compiled,
+            "timing": timing}
 
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     run_training(args)
     return 0
 

@@ -137,13 +137,12 @@ def _moe_ffn_deferred(pl: Pytree, x: jax.Array, cfg: ArchConfig,
         y_partial = jax.vmap(route)(x_blk, probs)      # f-shard partial sums
         return jax.lax.psum(y_partial, "model")
 
-    from jax.experimental.shard_map import shard_map
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, specs["router"], specs["w_gate"],
                   specs["w_up"], specs["w_down"]),
         out_specs=x_spec,
-        check_rep=False)
+        check_vma=False)
     return mapped(x, pl["router"], pl["w_gate"], pl["w_up"], pl["w_down"])
 
 

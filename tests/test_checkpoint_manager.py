@@ -413,6 +413,36 @@ def test_subprocess_writer_records_run_meta(tmp_path):
     assert read_run_meta(d, 2) == {"mixing": {"mode": "static"}}
 
 
+def test_subprocess_writer_child_never_initialises_a_backend(tmp_path):
+    """The commit child runs in a fresh interpreter next to a parent that
+    may hold the chip: its loop must commit without ever creating a JAX
+    backend.  Run the child's main in a fresh process and ask JAX."""
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    script = f"""
+import sys, queue
+sys.path.insert(0, {os.path.abspath(src)!r})
+import numpy as np
+from jax._src import xla_bridge
+from repro.checkpoint.io import snapshot_tree
+from repro.checkpoint.manager import _subprocess_commit_loop
+arrays, meta = snapshot_tree(3, {{"w": np.full((2, 3), 7.0, np.float32)}})
+jobq, ackq = queue.Queue(), queue.Queue()
+jobq.put((3, arrays, meta))
+jobq.put(None)
+_subprocess_commit_loop({str(tmp_path)!r}, None, None, [], jobq, ackq)
+print(ackq.get()[0], ackq.get()[0], xla_bridge.backends_are_initialized())
+"""
+    env = dict(os.environ)
+    env.pop("JAX_PLATFORMS", None)
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["ok", "end", "False"]
+    assert complete_steps(str(tmp_path)) == [3]
+
+
 def test_writer_choice_validated(tmp_path):
     with pytest.raises(ValueError, match="writer"):
         CheckpointManager(str(tmp_path), writer="fork")

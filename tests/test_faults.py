@@ -489,11 +489,19 @@ def test_trimmed_mean_step_survives_scale_byzantine():
     rng = np.random.default_rng(2)
     batch = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
     loss = lambda p, b: jnp.sum((p - b) ** 2)
-    # seed 32 realizes at most ONE corrupt sender per step over these 30
-    # steps (11 corrupt events) — within trim=1's byzantine tolerance; a
-    # step with 2+ corrupt senders is legitimately allowed to diverge.
-    faults = make_faults(m, corrupt_rate=0.1, corrupt_mode="scale",
-                         corrupt_scale=1e6, seed=32)
+    # trim=1 tolerates ONE corrupt sender per step; a step with 2+ is
+    # legitimately allowed to diverge.  Which seeds stay within that
+    # depends on the PRNG implementation (jax_threefry_partitionable
+    # changed the draws), so take the first seed whose realized stream
+    # has corruption and never 2+ corrupt senders in one step.
+    def stream(seed):
+        f = make_faults(m, corrupt_rate=0.1, corrupt_mode="scale",
+                        corrupt_scale=1e6, seed=seed)
+        per_step = jax.vmap(lambda k: f.realize(k)[1].sum())(jnp.arange(30))
+        return f, np.asarray(per_step)
+    faults, per_step = next(
+        (f, c) for f, c in map(stream, range(100))
+        if c.max() == 1 and c.sum() >= 8)
     step = make_decentralized_step(loss, top, S.harmonic(0.1),
                                    faults=faults, aggregation="trimmed_mean",
                                    trim=1, donate=False)
@@ -502,7 +510,7 @@ def test_trimmed_mean_step_survives_scale_byzantine():
     for i in range(30):
         state, aux = step(state, batch, jax.random.key(i))
         corrupt += int(aux["fault_corrupt"])
-    assert corrupt > 0  # byzantine steps actually happened
+    assert corrupt == per_step.sum() > 0  # byzantine steps happened
     p = np.asarray(state.params)
     assert np.all(np.isfinite(p)) and np.max(np.abs(p)) < 100.0
 

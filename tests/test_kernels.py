@@ -158,6 +158,62 @@ def test_kernel_rng_replay_parity_tpu():
     assert np.array_equal(np.asarray(out), np.asarray(replay))
 
 
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="needs the Mosaic PRNG lowering")
+def test_kernel_rng_tile_streams_depend_only_on_coordinates_tpu():
+    """Each tile's draw is a function of (seed, i, j) alone: widening the
+    grid leaves the existing tiles' bits unchanged, and distinct tiles
+    (and distinct seeds) draw distinct bits."""
+    from repro.kernels import obfuscate_update_krng
+    seed = jnp.asarray([7, 11], jnp.uint32)
+
+    def bits(rows, cols, s=seed):
+        x = jnp.zeros((rows, cols), jnp.float32)
+        return np.asarray(obfuscate_update_krng(
+            x, x, s, 0.05, 0.0, -1.0, block=(8, 256))[1])
+
+    small, wide = bits(16, 512), bits(16, 1024)
+    assert np.array_equal(small, wide[:, :512])
+    tiles = [wide[r:r + 8, c:c + 256] for r in (0, 8) for c in (0, 256, 512)]
+    for a in range(len(tiles)):
+        for b in range(a + 1, len(tiles)):
+            assert not np.array_equal(tiles[a], tiles[b]), (a, b)
+    other = bits(16, 512, jnp.asarray([7, 12], jnp.uint32))
+    assert not np.array_equal(small, other)
+
+
+def test_kernel_rng_seed_words_fresh_every_step():
+    """The in-kernel draw is seeded with the step index and random bits;
+    with a tile's coordinates folded in (one row of tiles, as on the fused
+    paths) no two steps and no two tiles of a step share seed words."""
+    from repro.core.pdsgd import krng_seed
+    from repro.kernels.obfuscate import tile_seed
+    key, cols = jax.random.key(3), jnp.arange(0, 400_000, 97)
+    seen = set()
+    for k in range(4):
+        seed = jnp.asarray(jax.jit(krng_seed)(key, jnp.int32(k)), jnp.int32)
+        assert int(seed[0]) == k
+        w0, w1 = tile_seed(seed, 0, cols)
+        words = {(int(w0), int(w)) for w in np.asarray(w1)}
+        assert len(words) == cols.size
+        assert seen.isdisjoint(words)
+        seen |= words
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="needs the Mosaic PRNG lowering")
+def test_kernel_rng_consecutive_steps_draw_fresh_bits_tpu():
+    """The same tile draws different Lambda bits at consecutive steps."""
+    from repro.core.pdsgd import krng_seed
+    from repro.kernels import obfuscate_update_krng
+    x, key = jnp.zeros((4, 1024), jnp.float32), jax.random.key(5)
+    a, b = (np.asarray(obfuscate_update_krng(
+        x, x, krng_seed(key, jnp.int32(k)), 0.05, 0.0, -1.0,
+        block=(4, 256))[1]) for k in (0, 1))
+    for c in range(0, 1024, 256):
+        assert not np.array_equal(a[:, c:c + 256], b[:, c:c + 256])
+
+
 def test_mask_from_bits_math():
     """The in-kernel mask math on synthetic bits: symmetric, zero diag,
     gated by the base adjacency, and each kept edge corresponds to a
@@ -338,6 +394,24 @@ def test_ring_krng_refuses_cpu_lowering():
         jax.block_until_ready(ring_obfuscate_gossip_krng(
             w_tab, b, perms, X, G, jnp.asarray([3, 9], jnp.int32), 0.1,
             interpret=True))
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="needs the Mosaic PRNG lowering")
+def test_ring_krng_replay_parity_tpu():
+    """The fused ring krng kernel exports its Λ bits; replaying them
+    through the HBM-bits ring kernel reproduces the output bit-for-bit,
+    over a grid of several column tiles."""
+    from repro.kernels import ring_obfuscate_gossip, ring_obfuscate_gossip_krng
+    w_tab, b, perms, X, G = _ring_tables(8, 1, 1536, seed=13)
+    seed = jnp.asarray([3, 9], jnp.uint32)
+    out, bits = ring_obfuscate_gossip_krng(w_tab, b, perms, X, G, seed, 0.1,
+                                           block_n=512)
+    replay = ring_obfuscate_gossip(w_tab, b, perms, X, G, bits, 0.1,
+                                   block_n=512)
+    assert np.array_equal(np.asarray(out), np.asarray(replay))
+    tiles = np.split(np.asarray(bits), 3, axis=1)
+    assert not np.array_equal(tiles[0], tiles[1])
 
 
 def test_ring_pdsgd_tree_matches_flat_kernel():

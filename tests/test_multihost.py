@@ -199,6 +199,20 @@ def test_agents_must_split_over_world():
         mh.launch(_args(["--world", "3"], None))
 
 
+def test_tpu_ranks_refused_beyond_one_per_host(monkeypatch):
+    """A rank process opens every chip of its host, so the launcher
+    refuses a second TPU-using rank before spawning any; CPU ranks (read
+    from JAX_PLATFORMS without a probe) pass."""
+    assert mh.rank_platform({"JAX_PLATFORMS": "cpu"}) == "cpu"
+    mh.check_rank_platform(4, {"JAX_PLATFORMS": "cpu"})
+    monkeypatch.setattr(mh, "rank_platform", lambda env: "tpu")
+    mh.check_rank_platform(1, {})
+    with pytest.raises(ValueError, match="TPU-using rank"):
+        mh.check_rank_platform(2, {})
+    with pytest.raises(ValueError, match="TPU-using rank"):
+        mh.launch(_args(["--world", "2"], None))
+
+
 def test_validate_agent_tiling_errors():
     """Satellite: `launch.mesh.validate_agent_tiling` refuses bad agent
     tilings with the fitting counts spelled out."""

@@ -118,3 +118,26 @@ def test_moe_deferred_matches_allreduce_multidevice():
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["rel"] < 1e-5, res
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Roofline shares use the peaks of the chip a record names; a chip
+    missing from the table is an error, never another chip's peaks."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "roofline.py")
+    spec = importlib.util.spec_from_file_location("roofline", path)
+    roofline = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(roofline)
+    v5e = roofline.peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+    assert "TPU v5e" in v5e["source"]
+    with pytest.raises(ValueError, match="no peak table"):
+        roofline.peaks("cpu")
+    rec = {"arch": "stablelm-3b", "kind": "train", "tokens": 1, "chips": 1,
+           "params": {"active": 1}, "device_kind": "cpu",
+           "memory": {"temp_bytes": 0, "argument_bytes": 0}}
+    with pytest.raises(ValueError, match="no peak table"):
+        roofline.analyze(rec)
+    del rec["device_kind"]
+    assert roofline.analyze(rec)["device_kind"] == "TPU v5 lite"

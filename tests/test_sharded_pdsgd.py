@@ -84,7 +84,12 @@ def test_leafwise_matches_concat_bitwise(m, seed, masked):
 
 def test_leafwise_mesh_trivial_matches_concat_bitwise():
     """The mesh flavor (shard_map obfuscate + einsum gossip) on a
-    trivially-sharded 1-device mesh: still bit-identical to concat."""
+    trivially-sharded 1-device mesh matches concat.  The obfuscate stage
+    is the same kernel, but the gossip stage is an XLA einsum over each
+    (m, ...) leaf instead of the kernel's blocked (m, m) @ (m, bn) dot:
+    XLA may order or FMA-contract the m-term sums differently, so the
+    two agree to float32 rounding (a few ulp on O(1) values), not
+    bitwise."""
     from jax.sharding import PartitionSpec as P
     m, seed = 4, 7
     mesh = jax.make_mesh((1, 1, 1), ("data", "fsdp", "model"),
@@ -97,7 +102,8 @@ def test_leafwise_mesh_trivial_matches_concat_bitwise():
     out = sharded_pdsgd_tree(W, B, x, g, bits, lam, interpret=True,
                              mesh=mesh, leaf_specs=specs)
     for k in _SHAPES:
-        assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k])), k
+        np.testing.assert_allclose(np.asarray(out[k]), np.asarray(ref[k]),
+                                   rtol=1e-6, atol=1e-6, err_msg=k)
 
 
 def test_sharded_tree_mesh_needs_specs_and_refuses_corrupt():
