@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace as tr
 from .mixing import MixingProcess, as_process
 from .privacy import agent_key, leaf_keys, obfuscated_gradient, sample_B
 from .schedules import Schedule
@@ -219,7 +220,8 @@ def pdsgd_update(
     if corrupt is not None and observe:
         raise ValueError("observation capture with corrupt links is not "
                          "an audited scenario")
-    B = sample_B(agent_key(jax.random.fold_in(key, 2), step, 0), support)
+    with tr.region(tr.STEP_MIX):
+        B = sample_B(agent_key(jax.random.fold_in(key, 2), step, 0), support)
     if use_pallas is None:
         from ..kernels import default_use_pallas
         use_pallas = default_use_pallas()
@@ -237,16 +239,19 @@ def pdsgd_update(
         if n_data * n_pod != m:
             raise ValueError(
                 f"torus_shape {n_pod}x{n_data} does not hold m={m} agents")
-        tabs = C.directional_weights(W, n_data, n_pod)
-        w_tab = jnp.concatenate([tabs["w_self"][:, None], tabs["w_dir"]],
-                                axis=1)
-        b_rows = C.rows_from_dense(B, n_data, n_pod)
-        perms = C.perm_stack(n_data, n_pod)
+        with tr.region(tr.STEP_MIX):
+            tabs = C.directional_weights(W, n_data, n_pod)
+            w_tab = jnp.concatenate([tabs["w_self"][:, None], tabs["w_dir"]],
+                                    axis=1)
+            b_rows = C.rows_from_dense(B, n_data, n_pod)
+            perms = C.perm_stack(n_data, n_pod)
         bits = seed = None
-        if runtime.resolve_kernel_rng(kernel_rng):
-            seed = krng_seed(key, step)
-        else:
-            bits = _per_agent_bits(jax.random.fold_in(key, 1), step, grads)
+        with tr.region(tr.OBFUSCATE):
+            if runtime.resolve_kernel_rng(kernel_rng):
+                seed = krng_seed(key, step)
+            else:
+                bits = _per_agent_bits(jax.random.fold_in(key, 1), step,
+                                       grads)
         out = ring_pdsgd_tree(w_tab, b_rows, perms, params, grads, bits,
                               lam_bar, interpret=interpret, observe=observe,
                               kernel_rng=kernel_rng, seed=seed)
@@ -268,7 +273,8 @@ def pdsgd_update(
                 "observation capture is defined on the concatenated wire "
                 "buffer; kernel_layout='leafwise' does not support it")
         from ..kernels import sharded_pdsgd_tree
-        bits = _per_agent_bits(jax.random.fold_in(key, 1), step, grads)
+        with tr.region(tr.OBFUSCATE):
+            bits = _per_agent_bits(jax.random.fold_in(key, 1), step, grads)
         return sharded_pdsgd_tree(W, B, params, grads, bits, lam_bar,
                                   mask=mask, interpret=interpret,
                                   corrupt=corrupt,
@@ -279,12 +285,14 @@ def pdsgd_update(
     if use_pallas:
         from ..kernels import fused_pdsgd_tree, runtime
         bits = seed = None
-        if runtime.resolve_kernel_rng(kernel_rng):
-            # seed the TPU PRNG from the same per-step Lambda key the HBM
-            # bits would have been drawn from; no bits staging at all
-            seed = krng_seed(key, step)
-        else:
-            bits = _per_agent_bits(jax.random.fold_in(key, 1), step, grads)
+        with tr.region(tr.OBFUSCATE):
+            if runtime.resolve_kernel_rng(kernel_rng):
+                # seed the TPU PRNG from the same per-step Lambda key the
+                # HBM bits would have been drawn from; no bits staging
+                seed = krng_seed(key, step)
+            else:
+                bits = _per_agent_bits(jax.random.fold_in(key, 1), step,
+                                       grads)
         out = fused_pdsgd_tree(W, B, params, grads, bits, lam_bar,
                                mask=mask, interpret=interpret,
                                observe=observe, corrupt=corrupt,
@@ -297,16 +305,20 @@ def pdsgd_update(
         new_params, flats = out
         x_flat, u_flat = flats["x"], flats["u"]
     else:
-        u = _per_agent_obfuscated(jax.random.fold_in(key, 1), step, grads,
-                                  lam_bar)
+        with tr.region(tr.OBFUSCATE):
+            u = _per_agent_obfuscated(jax.random.fold_in(key, 1), step,
+                                      grads, lam_bar)
         if corrupt is not None:
             from ..faults.inject import guarded_gossip_mix
-            return guarded_gossip_mix(W, B, params, u, corrupt,
-                                      mode=corrupt_mode,
-                                      scale=corrupt_scale, clip=guard_clip)
-        mixed = gossip_mix(W, params)
-        descent = gossip_mix(B, u)
-        new_params = jax.tree.map(lambda a, b: a - b, mixed, descent)
+            with tr.region(tr.GOSSIP):
+                return guarded_gossip_mix(W, B, params, u, corrupt,
+                                          mode=corrupt_mode,
+                                          scale=corrupt_scale,
+                                          clip=guard_clip)
+        with tr.region(tr.GOSSIP):
+            mixed = gossip_mix(W, params)
+            descent = gossip_mix(B, u)
+            new_params = jax.tree.map(lambda a, b: a - b, mixed, descent)
         if not observe:
             return new_params
         from ..privacy import observe as O
@@ -327,7 +339,8 @@ def dsgd_update(
     lam: jax.Array,
 ) -> Pytree:
     """Conventional decentralized SGD [19]: x^{k+1} = W x^k - lam g^k."""
-    mixed = gossip_mix(W, params)
+    with tr.region(tr.GOSSIP):
+        mixed = gossip_mix(W, params)
     return jax.tree.map(lambda a, g: a - lam * g.astype(a.dtype), mixed, grads)
 
 
@@ -375,11 +388,12 @@ def dp_dsgd_update(
     """Differential-privacy baseline: Gaussian noise added to the gradient
     before the conventional update (Table I of the paper)."""
     leaves, treedef = jax.tree.flatten(grads)
-    keys = jax.random.split(key, len(leaves))
-    noisy = [
-        g + sigma_dp * jax.random.normal(k, g.shape, dtype=g.dtype)
-        for k, g in zip(keys, leaves)
-    ]
+    with tr.region(tr.OBFUSCATE):
+        keys = jax.random.split(key, len(leaves))
+        noisy = [
+            g + sigma_dp * jax.random.normal(k, g.shape, dtype=g.dtype)
+            for k, g in zip(keys, leaves)
+        ]
     return dsgd_update(params, jax.tree.unflatten(treedef, noisy), W=W, lam=lam)
 
 
@@ -536,27 +550,43 @@ def make_decentralized_step(
 
     def apply_update(state, batch, key, lam_bar):
         alive = corrupt = rejoin = None
-        if faults is None:
-            W, support, mask = process.realize(state.step)
-        else:
-            from ..faults import realize_coupling
-            W, support, mask, alive, corrupt = realize_coupling(
-                process, faults, state.step)
-        # `held` is this step's hold/rollback anchor: the pre-update
-        # state, with rejoining agents already warm started — what down
-        # agents freeze to and what a skipped non-finite step reverts to.
-        held = state.params
-        if faults is not None and faults.has_crash and not faults.is_failstop:
-            prev = jnp.where(
-                state.step > 0,
-                faults.alive_at(jnp.maximum(state.step - 1, 0)),
-                jnp.ones_like(alive))
-            rejoin = alive * (1.0 - prev)
-            if faults.rejoin == "neighbor-avg":
-                from ..faults.inject import neighbor_avg_warmstart
-                held, _ = neighbor_avg_warmstart(state.params, mask,
-                                                 alive, prev)
-        losses, grads = grad_fn(held, batch)
+        with tr.region(tr.STEP_MIX):
+            if faults is None:
+                W, support, mask = process.realize(state.step)
+            else:
+                from ..faults import realize_coupling
+                W, support, mask, alive, corrupt = realize_coupling(
+                    process, faults, state.step)
+            # `held` is this step's hold/rollback anchor: the pre-update
+            # state, with rejoining agents already warm started — what
+            # down agents freeze to and what a skipped non-finite step
+            # reverts to.
+            held = state.params
+            if (faults is not None and faults.has_crash
+                    and not faults.is_failstop):
+                prev = jnp.where(
+                    state.step > 0,
+                    faults.alive_at(jnp.maximum(state.step - 1, 0)),
+                    jnp.ones_like(alive))
+                rejoin = alive * (1.0 - prev)
+                if faults.rejoin == "neighbor-avg":
+                    from ..faults.inject import neighbor_avg_warmstart
+                    held, _ = neighbor_avg_warmstart(state.params, mask,
+                                                     alive, prev)
+        with tr.region(tr.STEP_MODEL):
+            losses, grads = grad_fn(held, batch)
+        with tr.region(tr.STEP_UPDATE):
+            new_params, new_tracker, observation = _update(
+                state, held, grads, key, lam_bar, W, support, mask, alive,
+                corrupt)
+        with tr.region(tr.STEP_REPORT):
+            return _report(state, held, losses, new_params, new_tracker,
+                           observation, alive, corrupt, rejoin)
+
+    def _update(state, held, grads, key, lam_bar, W, support, mask, alive,
+                corrupt):
+        """The update of `apply_update`: (new params, new tracker,
+        observation or None)."""
         if grad_clip is not None:
             from .privacy import clip_gradients
             grads = clip_gradients(grads, grad_clip)
@@ -565,15 +595,18 @@ def make_decentralized_step(
         if algorithm == "pdsgd":
             if aggregation == "trimmed_mean":
                 from ..faults.inject import trimmed_mean_mix
-                u = _per_agent_obfuscated(jax.random.fold_in(key, 1),
-                                          state.step, grads, lam_bar)
+                with tr.region(tr.OBFUSCATE):
+                    u = _per_agent_obfuscated(jax.random.fold_in(key, 1),
+                                              state.step, grads, lam_bar)
                 cz = (corrupt if corrupt is not None
                       else jnp.zeros((num_agents,), jnp.float32))
-                new_params = trimmed_mean_mix(
-                    held, u, support, cz, trim=trim,
-                    mode=faults.corrupt_mode if faults is not None else "nan",
-                    scale=(faults.corrupt_scale if faults is not None
-                           else 1e4))
+                with tr.region(tr.GOSSIP):
+                    new_params = trimmed_mean_mix(
+                        held, u, support, cz, trim=trim,
+                        mode=(faults.corrupt_mode if faults is not None
+                              else "nan"),
+                        scale=(faults.corrupt_scale if faults is not None
+                               else 1e4))
             else:
                 corrupting = faults is not None and faults.has_corruption
                 out = pdsgd_update(
@@ -611,11 +644,14 @@ def make_decentralized_step(
             # params with it before producing y^{k+1}.  Don't swap one for
             # the other without re-deriving the phase.
             y_prev, g_prev = state.tracker
+            with tr.region(tr.GOSSIP):
+                mixed_y = gossip_mix(W, y_prev)
             y = jax.tree.map(lambda t, g, gp: t + g - gp,
-                             gossip_mix(W, y_prev), grads, g_prev)
+                             mixed_y, grads, g_prev)
+            with tr.region(tr.GOSSIP):
+                mixed = gossip_mix(W, held)
             new_params = jax.tree.map(
-                lambda a, t: a - lam_bar * t.astype(a.dtype),
-                gossip_mix(W, held), y)
+                lambda a, t: a - lam_bar * t.astype(a.dtype), mixed, y)
             new_tracker = (y, grads)
         elif algorithm == "dp_dsgd":
             new_params = dp_dsgd_update(
@@ -637,6 +673,11 @@ def make_decentralized_step(
         # can't be dragged backward by somebody else's non-finite step.
         if alive is not None:
             new_params = jax.tree.map(_rowwise(alive), new_params, held)
+        return new_params, new_tracker, observation
+
+    def _report(state, held, losses, new_params, new_tracker, observation,
+                alive, corrupt, rejoin):
+        """The sentinels and the aux of `apply_update`: (new state, aux)."""
         nonfinite = None
         if nan_policy != "off":
             finite = jnp.isfinite(losses).all()
@@ -679,8 +720,10 @@ def make_decentralized_step(
                                   tracker=new_tracker), aux
 
     def step_fn(state: DecentralizedState, batch, key: jax.Array):
-        lam_bar = jnp.asarray(
-            schedule(state.step.astype(jnp.float32), 0), dtype=jnp.float32)
+        with tr.region(tr.STEP_UPDATE):
+            lam_bar = jnp.asarray(
+                schedule(state.step.astype(jnp.float32), 0),
+                dtype=jnp.float32)
         return apply_update(state, batch, key, lam_bar)
 
     device_schedule = not force_host_schedule

@@ -13,16 +13,22 @@ pre-sharded over the agent torus instead of being replicated by the first
 jit invocation.  With ``mesh=None`` it degrades to `jnp.asarray` — the right
 thing on a single-device CPU container, and still overlaps H2D with compute
 because the transfer happens on the worker thread.
+
+Host spans (`repro.trace`): the worker's synthesis is ``repro.data.produce``
+and its placement ``repro.data.place``; the consumer's wait for the queue is
+``repro.data.wait``.  Each carries the chunk's first step as ``step``, so a
+profiler trace links the chunk a loop consumed to the work that made it.
 """
 from __future__ import annotations
 
 import queue
 import threading
 import weakref
-from typing import Any, Callable, Iterable, Iterator
+from typing import Any, Callable, Iterable
 
 import jax
 
+from .. import trace as tr
 from .pipeline import BATCH_LOGICAL, CHUNK_LOGICAL
 from .worker import END as _END
 from .worker import bounded_put as _bounded_put
@@ -31,7 +37,26 @@ from .worker import shutdown_worker as _shutdown_worker
 __all__ = ["Prefetcher", "make_placer", "prefetch_chunks"]
 
 
-def _worker_loop(it: Iterator, place: Callable | None,
+class _Labelled:
+    """The source, each item's synthesis inside a ``repro.data.produce``
+    span; ``step`` is the label of the item last returned."""
+
+    def __init__(self, source: Iterable, first_step: int, stride: int):
+        self._it = iter(source)
+        self._next, self._stride = first_step, stride
+        self.step = None
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        with tr.span(tr.DATA_PRODUCE, step=self._next):
+            item = next(self._it)
+        self.step, self._next = self._next, self._next + self._stride
+        return item
+
+
+def _worker_loop(it: _Labelled, place: Callable | None,
                  stop: threading.Event, q: queue.Queue):
     # Module-level (no Prefetcher reference): the thread must not keep the
     # owning Prefetcher alive, or its GC finalizer could never run.
@@ -40,8 +65,10 @@ def _worker_loop(it: Iterator, place: Callable | None,
         for item in it:
             if stop.is_set():
                 return
-            _bounded_put(stop, q,
-                         (place(item) if place is not None else item, None))
+            if place is not None:
+                with tr.span(tr.DATA_PLACE, step=it.step):
+                    item = place(item)
+            _bounded_put(stop, q, (item, None))
     except BaseException as e:  # re-raised by the consumer
         end = (_END, e)
     finally:
@@ -87,18 +114,23 @@ class Prefetcher:
     exceptions re-raise in the consumer.  `close()` (also via context
     manager / generator ``.close()`` protocol) stops the worker promptly
     even when the queue is full and joins it — no leaked threads.
+
+    Item n is labelled step ``first_step + n * stride`` in the host spans
+    (`prefetch_chunks` gives a chunk's first training step).
     """
 
     def __init__(self, source: Iterable, place: Callable | None = None,
-                 depth: int = 2):
+                 depth: int = 2, first_step: int = 0, stride: int = 1):
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
         self._queue: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._exhausted = False
+        self._next_step, self._stride = first_step, stride
         self._thread = threading.Thread(
             target=_worker_loop,
-            args=(iter(source), place, self._stop, self._queue),
+            args=(_Labelled(source, first_step, stride), place, self._stop,
+                  self._queue),
             name="repro-data-prefetch", daemon=True)
         self._thread.start()
         # Abandoned-iterator safety net: an un-close()d, un-exhausted
@@ -121,18 +153,29 @@ class Prefetcher:
     def __next__(self):
         if self._exhausted or self._stop.is_set():
             raise StopIteration
+        with tr.span(tr.DATA_WAIT, step=self._next_step):
+            item, err = self._get()
+        if err is not None:
+            self._exhausted = True
+            raise err
+        if item is _END:
+            self._exhausted = True
+            raise StopIteration
+        self._next_step += self._stride
+        return item
+
+    def _get(self):
+        """The next (item, error) the worker posted."""
         while True:
             try:
-                item, err = self._queue.get(timeout=self._POLL_S)
-                break
+                return self._queue.get(timeout=self._POLL_S)
             except queue.Empty:
                 if self._thread.is_alive():
                     continue
             # Dead worker: drain once more without blocking — it may have
             # posted between the timeout and the liveness check.
             try:
-                item, err = self._queue.get_nowait()
-                break
+                return self._queue.get_nowait()
             except queue.Empty:
                 self._exhausted = True
                 raise RuntimeError(
@@ -140,13 +183,6 @@ class Prefetcher:
                     "end-of-stream; the chunk stream is torn (not an "
                     "exhausted source — those end with a sentinel)"
                 ) from None
-        if err is not None:
-            self._exhausted = True
-            raise err
-        if item is _END:
-            self._exhausted = True
-            raise StopIteration
-        return item
 
     def close(self, join_timeout: float = 5.0):
         """Stop the worker and join it; idempotent.
@@ -189,4 +225,4 @@ def prefetch_chunks(pipeline, unroll_k: int, start_step: int = 0,
     return Prefetcher(
         pipeline.chunks(unroll_k, start_step=start_step,
                         num_chunks=num_chunks, agent_slice=agent_slice),
-        place=place, depth=depth)
+        place=place, depth=depth, first_step=start_step, stride=unroll_k)

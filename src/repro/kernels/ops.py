@@ -11,6 +11,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from .. import trace as tr
 from .flash_attention import flash_attention
 from .gossip import (gossip_update, guarded_gossip_update,
                      masked_gossip_update, masked_gossip_update_krng,
@@ -169,57 +170,64 @@ def fused_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
     if use_mask_krng and corrupt is not None:
         raise ValueError("in-kernel mask draw does not compose with "
                          "corrupt injection; pass the realized mask")
-    x_flat, sizes, leaves = _flatten_concat(x_tree)
-    g_flat, _, _ = _flatten_concat(g_tree)
-    x_flat, pad = _pad_cols(x_flat, 512)
-    g_flat, _ = _pad_cols(g_flat, 512)
+    if corrupt is not None and mask is None:
+        raise ValueError(
+            "corrupt injection needs the realized edge mask; compose "
+            "faults through faults.realize_coupling")
+    with tr.region(tr.LAYOUT):
+        x_flat, sizes, leaves = _flatten_concat(x_tree)
+        g_flat, _, _ = _flatten_concat(g_tree)
+        x_flat, pad = _pad_cols(x_flat, 512)
+        g_flat, _ = _pad_cols(g_flat, 512)
+        bits_flat = None
+        if not use_krng:
+            bits_flat, _, _ = _flatten_concat(bits_tree)
+            bits_flat, _ = _pad_cols(bits_flat, 512)
     # w_self=0, b_self=-1 turns the self-term kernel into u = lambda ∘ g.
-    bits_flat = None
-    if use_krng:
-        u_flat, bits_flat = obfuscate_update_krng(
-            x_flat, g_flat, seed, lam_bar, jnp.float32(0.0),
-            jnp.float32(-1.0), block=(x_flat.shape[0], 256),
-            interpret=interpret)
-    else:
-        bits_flat, _, _ = _flatten_concat(bits_tree)
-        bits_flat, _ = _pad_cols(bits_flat, 512)
-        u_flat = obfuscate_update(x_flat, g_flat, bits_flat, lam_bar,
-                                  jnp.float32(0.0), jnp.float32(-1.0),
-                                  block=(x_flat.shape[0], 256),
-                                  interpret=interpret)
-    if corrupt is not None:
-        if mask is None:
-            raise ValueError(
-                "corrupt injection needs the realized edge mask; compose "
-                "faults through faults.realize_coupling")
-        from ..faults.inject import poison_transmit
-        xt = poison_transmit(x_flat, corrupt, corrupt_mode, corrupt_scale)
-        ut = poison_transmit(u_flat, corrupt, corrupt_mode, corrupt_scale)
-        out = guarded_gossip_update(mask, B, x_flat, u_flat, xt, ut,
-                                    guard_clip, interpret=interpret)
-    elif use_mask_krng:
-        m = x_flat.shape[0]
-        adj = mask_adj
-        if adj is None:
-            adj = 1.0 - jnp.eye(m, dtype=jnp.float32)
-        out, _ = masked_gossip_update_krng(mask_seed, mask_keep_prob, adj,
-                                           B, x_flat, u_flat,
-                                           interpret=interpret)
-    elif mask is not None:
-        out = masked_gossip_update(mask, B, x_flat, u_flat,
-                                   interpret=interpret)
-    else:
-        out = gossip_update(W, B, x_flat, u_flat, interpret=interpret)
-    if pad:
-        out = out[:, :-pad]
-    out_tree = _unflatten(out, sizes, leaves, x_tree)
-    if not observe:
-        return out_tree
-    ncols = sum(sizes)
-    flats = {"x": x_flat[:, :ncols].astype(jnp.float32),
-             "u": u_flat[:, :ncols].astype(jnp.float32)}
-    if use_krng:
-        flats["bits"] = bits_flat[:, :ncols]
+    with tr.region(tr.OBFUSCATE):
+        if use_krng:
+            u_flat, bits_flat = obfuscate_update_krng(
+                x_flat, g_flat, seed, lam_bar, jnp.float32(0.0),
+                jnp.float32(-1.0), block=(x_flat.shape[0], 256),
+                interpret=interpret)
+        else:
+            u_flat = obfuscate_update(x_flat, g_flat, bits_flat, lam_bar,
+                                      jnp.float32(0.0), jnp.float32(-1.0),
+                                      block=(x_flat.shape[0], 256),
+                                      interpret=interpret)
+    with tr.region(tr.GOSSIP):
+        if corrupt is not None:
+            from ..faults.inject import poison_transmit
+            xt = poison_transmit(x_flat, corrupt, corrupt_mode,
+                                 corrupt_scale)
+            ut = poison_transmit(u_flat, corrupt, corrupt_mode,
+                                 corrupt_scale)
+            out = guarded_gossip_update(mask, B, x_flat, u_flat, xt, ut,
+                                        guard_clip, interpret=interpret)
+        elif use_mask_krng:
+            m = x_flat.shape[0]
+            adj = mask_adj
+            if adj is None:
+                adj = 1.0 - jnp.eye(m, dtype=jnp.float32)
+            out, _ = masked_gossip_update_krng(mask_seed, mask_keep_prob,
+                                               adj, B, x_flat, u_flat,
+                                               interpret=interpret)
+        elif mask is not None:
+            out = masked_gossip_update(mask, B, x_flat, u_flat,
+                                       interpret=interpret)
+        else:
+            out = gossip_update(W, B, x_flat, u_flat, interpret=interpret)
+    with tr.region(tr.LAYOUT):
+        if pad:
+            out = out[:, :-pad]
+        out_tree = _unflatten(out, sizes, leaves, x_tree)
+        if not observe:
+            return out_tree
+        ncols = sum(sizes)
+        flats = {"x": x_flat[:, :ncols].astype(jnp.float32),
+                 "u": u_flat[:, :ncols].astype(jnp.float32)}
+        if use_krng:
+            flats["bits"] = bits_flat[:, :ncols]
     return out_tree, flats
 
 
@@ -257,38 +265,43 @@ def ring_pdsgd_tree(w_tab: jax.Array, b_tab: jax.Array, perms: jax.Array,
     if kernel_rng and seed is None:
         raise ValueError("kernel_rng=True needs a (2,) seed "
                          "(derive from the step's Lambda key)")
-    x_flat, sizes, leaves = _flatten_concat(x_tree)
-    g_flat, _, _ = _flatten_concat(g_tree)
-    x_flat, pad = _pad_cols(x_flat, 512)
-    g_flat, _ = _pad_cols(g_flat, 512)
-    if use_krng:
-        res = ring_obfuscate_gossip_krng(w_tab, b_tab, perms, x_flat,
-                                         g_flat, seed, lam_bar,
-                                         capture=observe,
-                                         interpret=interpret)
-        out = res[0]
-        flats = {"v": res[2], "u": res[3]} if observe else None
-    else:
-        bits_flat, _, _ = _flatten_concat(bits_tree)
-        bits_flat, _ = _pad_cols(bits_flat, 512)
-        res = ring_obfuscate_gossip(w_tab, b_tab, perms, x_flat, g_flat,
-                                    bits_flat, lam_bar, capture=observe,
-                                    interpret=interpret)
-        if observe:
-            out, v, u = res
-            flats = {"v": v, "u": u}
+    with tr.region(tr.LAYOUT):
+        x_flat, sizes, leaves = _flatten_concat(x_tree)
+        g_flat, _, _ = _flatten_concat(g_tree)
+        x_flat, pad = _pad_cols(x_flat, 512)
+        g_flat, _ = _pad_cols(g_flat, 512)
+        if not use_krng:
+            bits_flat, _, _ = _flatten_concat(bits_tree)
+            bits_flat, _ = _pad_cols(bits_flat, 512)
+    # one kernel draws Lambda, obfuscates and runs the ring: it is the gossip
+    with tr.region(tr.GOSSIP):
+        if use_krng:
+            res = ring_obfuscate_gossip_krng(w_tab, b_tab, perms, x_flat,
+                                             g_flat, seed, lam_bar,
+                                             capture=observe,
+                                             interpret=interpret)
+            out = res[0]
+            flats = {"v": res[2], "u": res[3]} if observe else None
         else:
-            out = res
-            flats = None
-    if pad:
-        out = out[:, :-pad]
-    out_tree = _unflatten(out, sizes, leaves, x_tree)
-    if not observe:
-        return out_tree
-    ncols = sum(sizes)
-    flats = {"x": x_flat[:, :ncols].astype(jnp.float32),
-             "u": flats["u"][:, :ncols].astype(jnp.float32),
-             "v": flats["v"][:, :, :ncols].astype(jnp.float32)}
+            res = ring_obfuscate_gossip(w_tab, b_tab, perms, x_flat, g_flat,
+                                        bits_flat, lam_bar, capture=observe,
+                                        interpret=interpret)
+            if observe:
+                out, v, u = res
+                flats = {"v": v, "u": u}
+            else:
+                out = res
+                flats = None
+    with tr.region(tr.LAYOUT):
+        if pad:
+            out = out[:, :-pad]
+        out_tree = _unflatten(out, sizes, leaves, x_tree)
+        if not observe:
+            return out_tree
+        ncols = sum(sizes)
+        flats = {"x": x_flat[:, :ncols].astype(jnp.float32),
+                 "u": flats["u"][:, :ncols].astype(jnp.float32),
+                 "v": flats["v"][:, :, :ncols].astype(jnp.float32)}
     return out_tree, flats
 
 
@@ -301,25 +314,29 @@ def _leaf_pdsgd(W, B, x, g, bits, lam_bar, mask, interpret,
     dim), so per-leaf results are bit-identical to the same columns of
     the concatenated buffer — the property tests pin this."""
     m = x.shape[0]
-    xf, pad = _pad_cols(x.reshape(m, -1), 512)
-    gf, _ = _pad_cols(g.reshape(m, -1), 512)
-    bf, _ = _pad_cols(bits.reshape(m, -1), 512)
-    u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
-                         jnp.float32(-1.0), block=(m, 256),
-                         interpret=interpret)
-    if corrupt is not None:
-        from ..faults.inject import poison_transmit
-        xt = poison_transmit(xf, corrupt, corrupt_mode, corrupt_scale)
-        ut = poison_transmit(u, corrupt, corrupt_mode, corrupt_scale)
-        out = guarded_gossip_update(mask, B, xf, u, xt, ut, guard_clip,
-                                    interpret=interpret)
-    elif mask is not None:
-        out = masked_gossip_update(mask, B, xf, u, interpret=interpret)
-    else:
-        out = gossip_update(W, B, xf, u, interpret=interpret)
-    if pad:
-        out = out[:, :-pad]
-    return out.reshape(x.shape).astype(x.dtype)
+    with tr.region(tr.LAYOUT):
+        xf, pad = _pad_cols(x.reshape(m, -1), 512)
+        gf, _ = _pad_cols(g.reshape(m, -1), 512)
+        bf, _ = _pad_cols(bits.reshape(m, -1), 512)
+    with tr.region(tr.OBFUSCATE):
+        u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
+                             jnp.float32(-1.0), block=(m, 256),
+                             interpret=interpret)
+    with tr.region(tr.GOSSIP):
+        if corrupt is not None:
+            from ..faults.inject import poison_transmit
+            xt = poison_transmit(xf, corrupt, corrupt_mode, corrupt_scale)
+            ut = poison_transmit(u, corrupt, corrupt_mode, corrupt_scale)
+            out = guarded_gossip_update(mask, B, xf, u, xt, ut, guard_clip,
+                                        interpret=interpret)
+        elif mask is not None:
+            out = masked_gossip_update(mask, B, xf, u, interpret=interpret)
+        else:
+            out = gossip_update(W, B, xf, u, interpret=interpret)
+    with tr.region(tr.LAYOUT):
+        if pad:
+            out = out[:, :-pad]
+        return out.reshape(x.shape).astype(x.dtype)
 
 
 def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
@@ -369,15 +386,18 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
     def leaf_obfuscate(x, g, bits, spec):
         def body(xl, gl, bl):
             m = xl.shape[0]
-            xf, pad = _pad_cols(xl.reshape(m, -1), 256)
-            gf, _ = _pad_cols(gl.reshape(m, -1), 256)
-            bf, _ = _pad_cols(bl.reshape(m, -1), 256)
-            u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
-                                 jnp.float32(-1.0), block=(m, 256),
-                                 interpret=interpret)
-            if pad:
-                u = u[:, :-pad]
-            return u.reshape(xl.shape).astype(xl.dtype)
+            with tr.region(tr.LAYOUT):
+                xf, pad = _pad_cols(xl.reshape(m, -1), 256)
+                gf, _ = _pad_cols(gl.reshape(m, -1), 256)
+                bf, _ = _pad_cols(bl.reshape(m, -1), 256)
+            with tr.region(tr.OBFUSCATE):
+                u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
+                                     jnp.float32(-1.0), block=(m, 256),
+                                     interpret=interpret)
+            with tr.region(tr.LAYOUT):
+                if pad:
+                    u = u[:, :-pad]
+                return u.reshape(xl.shape).astype(xl.dtype)
         return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(x, g, bits)
 
@@ -385,11 +405,13 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
                           leaf_specs)
     if mask is not None:
         from ..core.mixing import metropolis_from_mask
-        W = metropolis_from_mask(mask)
+        with tr.region(tr.STEP_MIX):
+            W = metropolis_from_mask(mask)
     mix = lambda M, t: jax.tree.map(
         lambda l: jnp.einsum("ij,j...->i...", M, l.astype(jnp.float32),
                              preferred_element_type=jnp.float32
                              ).astype(l.dtype), t)
-    mixed = mix(W, x_tree)
-    desc = mix(B, u_tree)
-    return jax.tree.map(jnp.subtract, mixed, desc)
+    with tr.region(tr.GOSSIP):
+        mixed = mix(W, x_tree)
+        desc = mix(B, u_tree)
+        return jax.tree.map(jnp.subtract, mixed, desc)

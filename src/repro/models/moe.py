@@ -25,6 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from .. import trace as tr
 from ..configs.base import ArchConfig
 from .common import ArrayDef
 
@@ -54,31 +55,33 @@ def _route_group(x: jax.Array, probs: jax.Array, w_gate: jax.Array,
     C = int(-(-T * k // E) * cfg.capacity_factor)
     C = max(1, min(C, T))
 
-    gates, eidx = jax.lax.top_k(probs, k)  # (T, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    with tr.region(tr.MOE_ROUTER):
+        gates, eidx = jax.lax.top_k(probs, k)  # (T, k)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    flat_e = eidx.reshape(T * k)
-    order = jnp.argsort(flat_e, stable=True)  # (T*k,)
-    sorted_e = flat_e[order]
-    seg_start = jnp.searchsorted(sorted_e, jnp.arange(E), side="left")
-    pos = jnp.arange(T * k) - seg_start[sorted_e]
-    valid = pos < C
-    buf_idx = jnp.where(valid, sorted_e * C + pos, E * C)
+    with tr.region(tr.MOE_EXPERTS):
+        flat_e = eidx.reshape(T * k)
+        order = jnp.argsort(flat_e, stable=True)  # (T*k,)
+        sorted_e = flat_e[order]
+        seg_start = jnp.searchsorted(sorted_e, jnp.arange(E), side="left")
+        pos = jnp.arange(T * k) - seg_start[sorted_e]
+        valid = pos < C
+        buf_idx = jnp.where(valid, sorted_e * C + pos, E * C)
 
-    x_sorted = x[order // k]  # (T*k, d)
-    buf = jnp.zeros((E * C, d), x.dtype).at[buf_idx].set(
-        x_sorted, mode="drop").reshape(E, C, d)
+        x_sorted = x[order // k]  # (T*k, d)
+        buf = jnp.zeros((E * C, d), x.dtype).at[buf_idx].set(
+            x_sorted, mode="drop").reshape(E, C, d)
 
-    g = jnp.einsum("ecd,edf->ecf", buf, w_gate)
-    u = jnp.einsum("ecd,edf->ecf", buf, w_up)
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-    y_buf = jnp.einsum("ecf,efd->ecd", h, w_down).reshape(E * C, d)
+        g = jnp.einsum("ecd,edf->ecf", buf, w_gate)
+        u = jnp.einsum("ecd,edf->ecf", buf, w_up)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        y_buf = jnp.einsum("ecf,efd->ecd", h, w_down).reshape(E * C, d)
 
-    y_sorted = jnp.where(valid[:, None],
-                         y_buf[jnp.minimum(buf_idx, E * C - 1)], 0.0)
-    inv = jnp.argsort(order, stable=True)
-    y_flat = y_sorted[inv].reshape(T, k, d)
-    return jnp.einsum("tkd,tk->td", y_flat, gates.astype(x.dtype))
+        y_sorted = jnp.where(valid[:, None],
+                             y_buf[jnp.minimum(buf_idx, E * C - 1)], 0.0)
+        inv = jnp.argsort(order, stable=True)
+        y_flat = y_sorted[inv].reshape(T, k, d)
+        return jnp.einsum("tkd,tk->td", y_flat, gates.astype(x.dtype))
 
 
 def moe_ffn_train(pl: Pytree, x: jax.Array, cfg: ArchConfig,
@@ -87,8 +90,10 @@ def moe_ffn_train(pl: Pytree, x: jax.Array, cfg: ArchConfig,
     their data shard)."""
     if cfg.moe_impl == "deferred" and mesh is not None:
         return _moe_ffn_deferred(pl, x, cfg, mesh)
-    logits = jnp.einsum("bsd,de->bse", x, pl["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    with tr.region(tr.MOE_ROUTER):
+        logits = jnp.einsum("bsd,de->bse", x,
+                            pl["router"]).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
     route = lambda x_row, p_row: _route_group(
         x_row, p_row, pl["w_gate"], pl["w_up"], pl["w_down"], cfg)
     return jax.vmap(route)(x, probs)

@@ -19,6 +19,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from .. import trace as tr
 from ..configs.base import ArchConfig
 from .common import (ArrayDef, apply_rope, attention, chunked_attention,
                      constrain, cross_entropy, decode_attention,
@@ -131,19 +132,22 @@ def _layer_train(pl: Pytree, x: jax.Array, cfg: ArchConfig,
                  window: int | None, mesh=None) -> jax.Array:
     from jax.ad_checkpoint import checkpoint_name
     B, S, d = x.shape
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    h = _norm(x, pl, "attn_norm", cfg)
-    q, k, v = _qkv(pl, h, positions, cfg)
-    o = _attn(q, k, v, cfg, window)
-    # the wo / w_down einsums contract the model-sharded dim — their outputs
-    # are the post-all-reduce activations (named for the remat policy)
-    x = x + checkpoint_name(jnp.einsum("bshk,hkd->bsd", o, pl["wo"]),
-                            "attn_out")
-    x = constrain(x, mesh, ("batch", "seq", None))
-    h = _norm(x, pl, "mlp_norm", cfg)
-    x = x + checkpoint_name(_ffn(pl, h, cfg, decode=False, mesh=mesh),
-                            "ffn_out")
-    return constrain(x, mesh, ("batch", "seq", None))
+    with tr.region(tr.ATTN):
+        positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+        h = _norm(x, pl, "attn_norm", cfg)
+        q, k, v = _qkv(pl, h, positions, cfg)
+        o = _attn(q, k, v, cfg, window)
+        # the wo / w_down einsums contract the model-sharded dim — their
+        # outputs are the post-all-reduce activations (named for the remat
+        # policy)
+        x = x + checkpoint_name(jnp.einsum("bshk,hkd->bsd", o, pl["wo"]),
+                                "attn_out")
+        x = constrain(x, mesh, ("batch", "seq", None))
+    with tr.region(tr.MLP):
+        h = _norm(x, pl, "mlp_norm", cfg)
+        x = x + checkpoint_name(_ffn(pl, h, cfg, decode=False, mesh=mesh),
+                                "ffn_out")
+        return constrain(x, mesh, ("batch", "seq", None))
 
 
 def _layer_prefill(pl: Pytree, x: jax.Array, cfg: ArchConfig,
@@ -224,8 +228,9 @@ def forward_train(params: Pytree, batch: dict, cfg: ArchConfig,
                   mesh=None) -> jax.Array:
     """Full-sequence logits for training (per-layer remat; unrolled layers by
     default, lax.scan over the stacked layer params when cfg.scan_layers)."""
-    x = embed_tokens(params, batch, cfg)
-    x = constrain(x, mesh, ("batch", "seq", None))
+    with tr.region(tr.EMBED):
+        x = embed_tokens(params, batch, cfg)
+        x = constrain(x, mesh, ("batch", "seq", None))
     if cfg.remat_policy == "save_collectives":
         policy = jax.checkpoint_policies.save_only_these_names(
             "attn_out", "ffn_out")
@@ -240,25 +245,30 @@ def forward_train(params: Pytree, batch: dict, cfg: ArchConfig,
     else:
         for i in range(cfg.num_layers):
             x = body(layer_slice(params["layers"], i), x)
-    x = _final_norm(params, x, cfg)
-    return unembed(params, x, cfg)
+    with tr.region(tr.HEAD):
+        x = _final_norm(params, x, cfg)
+        return unembed(params, x, cfg)
 
 
 def loss_fn(params: Pytree, batch: dict, cfg: ArchConfig,
             mesh=None) -> jax.Array:
     logits = forward_train(params, batch, cfg, mesh=mesh)
-    weights = batch.get("loss_weights")
-    if weights is None and cfg.num_prefix_embeds:
-        # do not train on modality-prefix positions
-        S = batch["labels"].shape[-1]
-        weights = (jnp.arange(S) >= cfg.num_prefix_embeds).astype(jnp.float32)
-        weights = jnp.broadcast_to(weights, batch["labels"].shape)
-    if weights is None:
-        return cross_entropy(logits, batch["labels"], cfg.vocab_size)
-    lf = logits.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(lf, axis=-1)
-    gold = jnp.take_along_axis(lf, batch["labels"][..., None], axis=-1)[..., 0]
-    return jnp.sum((logz - gold) * weights) / jnp.maximum(weights.sum(), 1.0)
+    with tr.region(tr.HEAD):
+        weights = batch.get("loss_weights")
+        if weights is None and cfg.num_prefix_embeds:
+            # do not train on modality-prefix positions
+            S = batch["labels"].shape[-1]
+            weights = (jnp.arange(S) >= cfg.num_prefix_embeds
+                       ).astype(jnp.float32)
+            weights = jnp.broadcast_to(weights, batch["labels"].shape)
+        if weights is None:
+            return cross_entropy(logits, batch["labels"], cfg.vocab_size)
+        lf = logits.astype(jnp.float32)
+        logz = jax.scipy.special.logsumexp(lf, axis=-1)
+        gold = jnp.take_along_axis(lf, batch["labels"][..., None],
+                                   axis=-1)[..., 0]
+        return (jnp.sum((logz - gold) * weights)
+                / jnp.maximum(weights.sum(), 1.0))
 
 
 def cache_len_for(cfg: ArchConfig, seq_len: int) -> int:
