@@ -45,15 +45,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .blocks import COMPILER_PARAMS, column_block
 from .obfuscate import tile_seed
 from .runtime import resolve_interpret
 
+# The ring kernels' column block (they stage (2, m, bn) in VMEM scratch of
+# their own); the dense kernels take theirs from `gossip_block`.
 DEFAULT_BLOCK_N = 512
 # f32 matmuls at full precision: on the TPU's MXU the default rounds f32
 # operands to bf16, which would round the mixing weights (W's rows then no
 # longer sum to 1) and every staged v_j the 0/1 shift matmul moves.  The
 # (m, m) operands make the extra MXU passes free next to the HBM traffic.
 _EXACT = jax.lax.Precision.HIGHEST
+
+
+def gossip_block(m: int, width: int, dtype, guarded: bool = False,
+                 interpret: bool = False) -> int:
+    """Column block of the dense gossip kernels over (m, width) buffers
+    of ``dtype``.  The plain and masked kernels stream X, U and x' and
+    hold five float32 (m, bn) temporaries (x, u widened, the two
+    products, the result).  The guarded kernel also streams the transmit
+    buffers and holds (m, m, bn) per-link tensors, m (m, bn) temporaries
+    each (the two products, their difference, the guard's select), so
+    its block narrows as m grows."""
+    s = jnp.dtype(dtype).itemsize
+    if guarded:
+        return column_block(m, width, (s,) * 5, 4 * m + 5, interpret)
+    return column_block(m, width, (s,) * 3, 5, interpret)
+
+
+def _block_n(block_n, X, interpret, guarded=False):
+    """The column block of a dense gossip call.  The last block may
+    overhang n: no column of x' reads another, and Pallas drops the
+    writes past the edge."""
+    m, n = X.shape
+    return min(block_n or gossip_block(m, n, X.dtype, guarded, interpret), n)
 
 
 def _gossip_kernel(w_ref, b_ref, x_ref, u_ref, o_ref):
@@ -67,7 +93,7 @@ def _gossip_kernel(w_ref, b_ref, x_ref, u_ref, o_ref):
 
 
 def gossip_update(W: jax.Array, B: jax.Array, X: jax.Array, U: jax.Array,
-                  block_n: int = DEFAULT_BLOCK_N,
+                  block_n: int | None = None,
                   interpret: bool | None = None) -> jax.Array:
     # interpret resolves in this un-jitted wrapper: top-level calls pick
     # up env flips by retracing; calls inside an outer jit bind it at
@@ -79,11 +105,10 @@ def gossip_update(W: jax.Array, B: jax.Array, X: jax.Array, U: jax.Array,
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def _gossip_update(W, B, X, U, block_n, interpret):
     m, n = X.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
+    bn = _block_n(block_n, X, interpret)
     return pl.pallas_call(
         _gossip_kernel,
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((m, m), lambda i: (0, 0)),
             pl.BlockSpec((m, m), lambda i: (0, 0)),
@@ -92,6 +117,7 @@ def _gossip_update(W, B, X, U, block_n, interpret):
         ],
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), X.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(W, B, X, U)
 
@@ -138,7 +164,7 @@ def _masked_gossip_kernel(mask_ref, b_ref, x_ref, u_ref, o_ref):
 
 
 def masked_gossip_update(mask: jax.Array, B: jax.Array, X: jax.Array,
-                         U: jax.Array, block_n: int = DEFAULT_BLOCK_N,
+                         U: jax.Array, block_n: int | None = None,
                          interpret: bool | None = None) -> jax.Array:
     """x' = metropolis(mask) @ X - B @ U, the mask -> re-weight -> gossip
     fusion for time-varying topologies.  ``mask`` is the (m, m) symmetric
@@ -152,11 +178,10 @@ def masked_gossip_update(mask: jax.Array, B: jax.Array, X: jax.Array,
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def _masked_gossip_update(mask, B, X, U, block_n, interpret):
     m, n = X.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
+    bn = _block_n(block_n, X, interpret)
     return pl.pallas_call(
         _masked_gossip_kernel,
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((m, m), lambda i: (0, 0)),
             pl.BlockSpec((m, m), lambda i: (0, 0)),
@@ -165,6 +190,7 @@ def _masked_gossip_update(mask, B, X, U, block_n, interpret):
         ],
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), X.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(mask, B, X, U)
 
@@ -201,7 +227,7 @@ def _masked_gossip_krng_kernel(seed_ref, prob_ref, adj_ref, b_ref, x_ref,
 
 def masked_gossip_update_krng(seed: jax.Array, keep_prob, adj: jax.Array,
                               B: jax.Array, X: jax.Array, U: jax.Array,
-                              block_n: int = DEFAULT_BLOCK_N,
+                              block_n: int | None = None,
                               interpret: bool | None = None):
     """TPU-only masked gossip with the Bernoulli edge-mask draw in-VMEM.
 
@@ -226,14 +252,13 @@ def masked_gossip_update_krng(seed: jax.Array, keep_prob, adj: jax.Array,
 def _masked_gossip_update_krng(seed, keep_prob, adj, B, X, U, block_n,
                                interpret):
     m, n = X.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
+    bn = _block_n(block_n, X, interpret)
     seed = jnp.asarray(seed, jnp.int32)
     assert seed.shape == (2,), seed.shape
     prob = jnp.asarray(keep_prob, jnp.float32).reshape(1)
     return pl.pallas_call(
         _masked_gossip_krng_kernel,
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),
             pl.BlockSpec((1,), lambda i: (0,)),
@@ -250,6 +275,7 @@ def _masked_gossip_update_krng(seed, keep_prob, adj, B, X, U, block_n,
             jax.ShapeDtypeStruct((m, n), X.dtype),
             jax.ShapeDtypeStruct((m, m), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(seed, prob, adj, B, X, U)
 
@@ -260,9 +286,9 @@ def _guarded_gossip_kernel(mask_ref, b_ref, x_ref, u_ref, xt_ref, ut_ref,
     survive a NaN/Inf transmit (one poisoned operand contaminates the
     whole dot-product row), so the off-diagonal accumulation is unrolled
     to the explicit per-link v_ij = w_ij xt_j - b_ij ut_j tensor, each
-    link guarded BEFORE the sum.  (m, m, bn) f32 lives in VMEM — ~2 MB at
-    m=32, bn=512, comfortably within budget at gossip's tiny m.  The
-    diagonal terms never cross a wire and use the clean x/u buffers."""
+    link guarded BEFORE the sum.  (m, m, bn) f32 lives in VMEM, so
+    `gossip_block(guarded=True)` narrows bn as m grows.  The diagonal
+    terms never cross a wire and use the clean x/u buffers."""
     mask = mask_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     m = mask.shape[0]
@@ -292,7 +318,7 @@ def _guarded_gossip_kernel(mask_ref, b_ref, x_ref, u_ref, xt_ref, ut_ref,
 def guarded_gossip_update(mask: jax.Array, B: jax.Array, X: jax.Array,
                           U: jax.Array, XT: jax.Array, UT: jax.Array,
                           clip: float | None,
-                          block_n: int = DEFAULT_BLOCK_N,
+                          block_n: int | None = None,
                           interpret: bool | None = None) -> jax.Array:
     """Fault-tolerant masked gossip: Metropolis re-weighting from the
     realized edge mask (as `masked_gossip_update`) with every
@@ -317,11 +343,10 @@ def guarded_gossip_update(mask: jax.Array, B: jax.Array, X: jax.Array,
                    static_argnames=("clip", "block_n", "interpret"))
 def _guarded_gossip_update(mask, B, X, U, XT, UT, clip, block_n, interpret):
     m, n = X.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
+    bn = _block_n(block_n, X, interpret, guarded=True)
     return pl.pallas_call(
         functools.partial(_guarded_gossip_kernel, clip=clip),
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((m, m), lambda i: (0, 0)),
             pl.BlockSpec((m, m), lambda i: (0, 0)),
@@ -332,6 +357,7 @@ def _guarded_gossip_update(mask, B, X, U, XT, UT, clip, block_n, interpret):
         ],
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), X.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(mask, B, X, U, XT, UT)
 

@@ -8,7 +8,8 @@ Without fusion the update reads/writes d-sized arrays four times
 (materialize lambda, materialize u = lambda*g, mix, subtract); fused it is
 one read of (x, g, bits) + one write of v — a ~3x HBM-traffic cut on an
 op that runs on every parameter, every step (d up to 34B here vs the
-paper's 1.7M).  Tiles are (8k, 128)-aligned for the VPU lanes.
+paper's 1.7M).  A tile is all m rows by a column block that
+`blocks.column_block` sizes from VMEM (`obfuscate_block`).
 
 On a real TPU the `bits` input disappears: `obfuscate_update_krng` seeds
 the per-core PRNG (`pltpu.prng_seed`, re-seeded per grid tile from
@@ -30,9 +31,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .blocks import COMPILER_PARAMS, column_block
 from .runtime import resolve_interpret
 
-DEFAULT_BLOCK = (256, 256)
+# float32 (m, bc) temporaries of the body: the uniform, lambda, x and g
+# widened, the result before its cast.
+_TEMPS = 5
+
+
+def obfuscate_block(m: int, width: int, x_dtype, g_dtype,
+                    interpret: bool = False) -> int:
+    """Column block of the obfuscate kernels over (m, width) buffers: x,
+    g and v stream in their dtypes and the bits as uint32, in or out."""
+    xs, gs = jnp.dtype(x_dtype).itemsize, jnp.dtype(g_dtype).itemsize
+    return column_block(m, width, (xs, gs, 4, xs), _TEMPS, interpret)
+
+
+def _grid(x, g, block, interpret):
+    """(br, bc) and the grid for an (R, C) call: ``block=None`` is one row
+    of tiles with the rule's column block.  The last column block may
+    overhang C: the kernel is elementwise, and Pallas drops the writes
+    past the edge."""
+    R, C = x.shape
+    if block is None:
+        block = (R, obfuscate_block(R, C, x.dtype, g.dtype, interpret))
+    br, bc = min(block[0], R), min(block[1], C)
+    assert R % br == 0, (x.shape, block)
+    return br, bc, (R // br, pl.cdiv(C, bc))
 
 
 def _obfuscate_math(x, g, bits, lam_bar, w_self, b_self, out_dtype):
@@ -55,12 +80,13 @@ def _obfuscate_kernel(x_ref, g_ref, bits_ref, scal_ref, o_ref):
 
 def obfuscate_update(x: jax.Array, g: jax.Array, bits: jax.Array,
                      lam_bar, w_self, b_self,
-                     block: tuple[int, int] = DEFAULT_BLOCK,
+                     block: tuple[int, int] | None = None,
                      interpret: bool | None = None) -> jax.Array:
     """x, g: (R, C) same shape; bits: (R, C) uint32.  Returns v same shape.
 
-    R/C are padded to the block grid by the caller (ops.py handles pytrees
-    and arbitrary shapes by flattening + padding).  ``interpret=None``
+    R is a multiple of the row block; ``block=None`` takes (R,
+    `obfuscate_block`), and the last column block may overhang C (ops.py
+    flattens pytrees and pads them to whole vregs).  ``interpret=None``
     defers to `runtime.default_interpret` (compiled on TPU, interpreter
     elsewhere); resolved in this un-jitted wrapper, so TOP-LEVEL calls pick
     up env-var flips by retracing.  Calls inside an outer jit (e.g. a
@@ -76,12 +102,10 @@ def obfuscate_update(x: jax.Array, g: jax.Array, bits: jax.Array,
 def _obfuscate_update(x, g, bits, lam_bar, w_self, b_self,
                       block, interpret):
     R, C = x.shape
-    br, bc = min(block[0], R), min(block[1], C)
-    assert R % br == 0 and C % bc == 0, (x.shape, block)
+    br, bc, grid = _grid(x, g, block, interpret)
     scal = jnp.stack([jnp.asarray(lam_bar, jnp.float32),
                       jnp.asarray(w_self, jnp.float32),
                       jnp.asarray(b_self, jnp.float32)])
-    grid = (R // br, C // bc)
     return pl.pallas_call(
         _obfuscate_kernel,
         grid=grid,
@@ -93,6 +117,7 @@ def _obfuscate_update(x, g, bits, lam_bar, w_self, b_self,
         ],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(x, g, bits, scal)
 
@@ -143,7 +168,7 @@ def _obfuscate_krng_kernel(x_ref, g_ref, seed_ref, scal_ref, o_ref, bits_ref):
 
 def obfuscate_update_krng(x: jax.Array, g: jax.Array, seed: jax.Array,
                           lam_bar, w_self, b_self,
-                          block: tuple[int, int] = DEFAULT_BLOCK,
+                          block: tuple[int, int] | None = None,
                           interpret: bool | None = None):
     """TPU-only obfuscation with in-VMEM randomness.
 
@@ -165,14 +190,12 @@ def obfuscate_update_krng(x: jax.Array, g: jax.Array, seed: jax.Array,
 def _obfuscate_update_krng(x, g, seed, lam_bar, w_self, b_self,
                            block, interpret):
     R, C = x.shape
-    br, bc = min(block[0], R), min(block[1], C)
-    assert R % br == 0 and C % bc == 0, (x.shape, block)
+    br, bc, grid = _grid(x, g, block, interpret)
     scal = jnp.stack([jnp.asarray(lam_bar, jnp.float32),
                       jnp.asarray(w_self, jnp.float32),
                       jnp.asarray(b_self, jnp.float32)])
     seed = jnp.asarray(seed, jnp.int32)
     assert seed.shape == (2,), seed.shape
-    grid = (R // br, C // bc)
     return pl.pallas_call(
         _obfuscate_krng_kernel,
         grid=grid,
@@ -190,5 +213,6 @@ def _obfuscate_update_krng(x, g, seed, lam_bar, w_self, b_self,
             jax.ShapeDtypeStruct((R, C), x.dtype),
             jax.ShapeDtypeStruct((R, C), jnp.uint32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(x, g, seed, scal)

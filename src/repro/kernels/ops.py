@@ -68,7 +68,7 @@ def obfuscate_tree(key: jax.Array, x_tree: Pytree, g_tree: Pytree,
     g_flat, _ = _pad_cols(g_flat, 256)
     bits = jax.random.bits(key, x_flat.shape, dtype=jnp.uint32)
     out = obfuscate_update(x_flat, g_flat, bits, lam_bar, w_self, b_self,
-                           block=(x_flat.shape[0], 256), interpret=interpret)
+                           interpret=interpret)
     if pad:
         out = out[:, :-pad]
     return _unflatten(out, sizes, leaves, x_tree)
@@ -188,12 +188,10 @@ def fused_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
         if use_krng:
             u_flat, bits_flat = obfuscate_update_krng(
                 x_flat, g_flat, seed, lam_bar, jnp.float32(0.0),
-                jnp.float32(-1.0), block=(x_flat.shape[0], 256),
-                interpret=interpret)
+                jnp.float32(-1.0), interpret=interpret)
         else:
             u_flat = obfuscate_update(x_flat, g_flat, bits_flat, lam_bar,
                                       jnp.float32(0.0), jnp.float32(-1.0),
-                                      block=(x_flat.shape[0], 256),
                                       interpret=interpret)
     with tr.region(tr.GOSSIP):
         if corrupt is not None:
@@ -320,8 +318,7 @@ def _leaf_pdsgd(W, B, x, g, bits, lam_bar, mask, interpret,
         bf, _ = _pad_cols(bits.reshape(m, -1), 512)
     with tr.region(tr.OBFUSCATE):
         u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
-                             jnp.float32(-1.0), block=(m, 256),
-                             interpret=interpret)
+                             jnp.float32(-1.0), interpret=interpret)
     with tr.region(tr.GOSSIP):
         if corrupt is not None:
             from ..faults.inject import poison_transmit
@@ -392,8 +389,7 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
                 bf, _ = _pad_cols(bl.reshape(m, -1), 256)
             with tr.region(tr.OBFUSCATE):
                 u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
-                                     jnp.float32(-1.0), block=(m, 256),
-                                     interpret=interpret)
+                                     jnp.float32(-1.0), interpret=interpret)
             with tr.region(tr.LAYOUT):
                 if pad:
                     u = u[:, :-pad]

@@ -3,9 +3,11 @@
 The TPU compiler is installed with JAX, and compiles for a chip that is
 described rather than attached (``topologies.get_topology_desc``).  Each
 test lowers one kernel at xlstm-125m width (m=4 agents, 95.6M bf16 params
-each, flattened and padded as `kernels.ops.fused_pdsgd_tree` does) and
-compiles it through Mosaic: what interpret mode accepts but the chip's
-compiler refuses (tile alignment, VMEM budget, PRNG seeding) fails here.
+each, flattened and padded as `kernels.ops.fused_pdsgd_tree` does) or at
+the granite-moe one-chip cut's width with the column block that
+`kernels.blocks` picks, and compiles it through Mosaic: what interpret
+mode accepts but the chip's compiler refuses (tile alignment, VMEM
+budget, PRNG seeding) fails here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all
@@ -18,15 +20,18 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.gossip import (_gossip_update,
+from repro.kernels.gossip import (_gossip_update, _guarded_gossip_update,
                                   _masked_gossip_update_krng,
-                                  _ring_obfuscate_gossip_krng)
+                                  _ring_obfuscate_gossip_krng, gossip_block)
 from repro.kernels.obfuscate import (_obfuscate_update,
-                                     _obfuscate_update_krng)
+                                     _obfuscate_update_krng, obfuscate_block)
 
 M = 4
 D = 95_626_240  # xlstm-125m params per agent, padded to the 512 grid
 BLOCK = (M, 256)
+# granite-moe-1b-a400m at 3 layers: params per agent, a multiple of 512, so
+# the fused update adds no pad
+GRANITE = 211_393_536
 
 
 @pytest.fixture(scope="module")
@@ -107,6 +112,59 @@ def test_ring_obfuscate_gossip_krng_compiles(one_chip):
     _compile(lambda w, b, p, x, g, seed, lam: _ring_obfuscate_gossip_krng(
         w, b, p, x, g, seed, lam, capture=False, block_n=512,
         interpret=False), tab, tab, perms, x, x, seed, _scalar(one_chip))
+
+
+def test_granite_block_is_wide():
+    """The rule gives the granite update blocks of tens of thousands of
+    columns, not 256 or 512: a few thousand grid steps a kernel, the last
+    block overhanging the buffer."""
+    for bc in (obfuscate_block(M, GRANITE, jnp.bfloat16, jnp.bfloat16),
+               gossip_block(M, GRANITE, jnp.bfloat16)):
+        assert bc >= 32768 and -(-GRANITE // bc) < 8000
+        assert GRANITE % 512 == 0 and GRANITE % bc
+
+
+def test_obfuscate_update_krng_compiles_granite(one_chip):
+    x = _sds(one_chip, (M, GRANITE), jnp.bfloat16)
+    seed = _sds(one_chip, (2,), jnp.uint32)
+    _compile(lambda x, g, seed, lam: _obfuscate_update_krng(
+        x, g, seed, lam, 0.0, -1.0, block=None, interpret=False),
+        x, x, seed, _scalar(one_chip))
+
+
+def test_obfuscate_update_compiles_granite(one_chip):
+    x = _sds(one_chip, (M, GRANITE), jnp.bfloat16)
+    bits = _sds(one_chip, (M, GRANITE), jnp.uint32)
+    _compile(lambda x, g, b, lam: _obfuscate_update(
+        x, g, b, lam, 0.0, -1.0, block=None, interpret=False),
+        x, x, bits, _scalar(one_chip))
+
+
+def test_gossip_update_compiles_granite(one_chip):
+    mat = _sds(one_chip, (M, M), jnp.float32)
+    x = _sds(one_chip, (M, GRANITE), jnp.bfloat16)
+    _compile(lambda w, b, x, u: _gossip_update(
+        w, b, x, u, block_n=None, interpret=False), mat, mat, x, x)
+
+
+def test_masked_gossip_update_krng_compiles_granite(one_chip):
+    seed = _sds(one_chip, (2,), jnp.uint32)
+    mat = _sds(one_chip, (M, M), jnp.float32)
+    x = _sds(one_chip, (M, GRANITE), jnp.bfloat16)
+    _compile(lambda seed, p, adj, b, x, u: _masked_gossip_update_krng(
+        seed, p, adj, b, x, u, block_n=None, interpret=False),
+        seed, _scalar(one_chip), mat, mat, x, x)
+
+
+def test_guarded_gossip_update_compiles_many_agents(one_chip):
+    """The guarded kernel holds (m, m, bn) float32 per-link tensors: at
+    32 agents its rule-picked block must still fit VMEM."""
+    m, n = 32, 1 << 24
+    mat = _sds(one_chip, (m, m), jnp.float32)
+    x = _sds(one_chip, (m, n), jnp.bfloat16)
+    _compile(lambda mk, b, x, u, xt, ut: _guarded_gossip_update(
+        mk, b, x, u, xt, ut, clip=1e3, block_n=None, interpret=False),
+        mat, mat, x, x, x, x)
 
 
 @pytest.mark.parametrize("kernel_rng", [True, False])

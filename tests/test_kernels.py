@@ -94,6 +94,140 @@ def test_tree_wrappers_match_core_update():
         np.testing.assert_allclose(np.asarray(out[name]), expect, atol=1e-5)
 
 
+# -- column blocks sized from VMEM (kernels.blocks) ------------------------
+
+# (streamed itemsizes, float32 temporaries) of one column, as the kernels
+# count them: obfuscate (x, g, bits, v), gossip (X, U, x'), and the
+# guarded gossip, whose per-link tensors grow with m.
+_KERNEL_COLUMNS = {
+    "obfuscate": lambda m: ((2, 2, 4, 2), 5),
+    "gossip": lambda m: ((2, 2, 2), 5),
+    "guarded": lambda m: ((2,) * 5, 4 * m + 5),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True])
+@pytest.mark.parametrize("width", [40, 700, 5_000, 95_626_240, 211_393_536])
+@pytest.mark.parametrize("m", [2, 4, 8, 32])
+@pytest.mark.parametrize("kernel", sorted(_KERNEL_COLUMNS))
+def test_column_block_rule(kernel, m, width, interpret):
+    """The block is a multiple of 512, no wider than the width rounded up
+    to 512, and its double-buffered blocks and temporaries fit the VMEM
+    budget by the pessimistic count; it is the widest 512 * 2**k that
+    does (unless the width caps it), and interpreted blocks stay under
+    the element cap."""
+    from repro.kernels.blocks import (INTERPRET_ELEMENTS, LANE_BLOCK,
+                                      VMEM_BUDGET, column_block, vmem_rows)
+    streamed, temps = _KERNEL_COLUMNS[kernel](m)
+    bc = column_block(m, width, streamed, temps, interpret)
+    per_col = (2 * sum(vmem_rows(m, s) * s for s in streamed)
+               + temps * vmem_rows(m, 4) * 4)
+    cap = -(-width // LANE_BLOCK) * LANE_BLOCK
+    assert bc % LANE_BLOCK == 0 and LANE_BLOCK <= bc <= cap
+    wide = bc * 2
+    fits = lambda c: (c * per_col <= VMEM_BUDGET
+                      and not (interpret and c * m > INTERPRET_ELEMENTS))
+    assert bc == LANE_BLOCK or fits(bc)
+    assert bc == cap or not fits(wide)
+    # sublane padding: a 4-row bf16 block counts as 16 rows
+    assert vmem_rows(4, 2) == 16 and vmem_rows(4, 4) == 8
+
+
+def test_column_block_guarded_narrows_with_agents():
+    """At the same width the guarded kernel's block is narrower than the
+    plain gossip's once m is large, and narrows as m grows; at granite's
+    4 agents both update kernels take one wide block."""
+    from repro.kernels.gossip import gossip_block
+    from repro.kernels.obfuscate import obfuscate_block
+    n, bf16 = 95_626_240, jnp.bfloat16
+    assert gossip_block(32, n, bf16, guarded=True) < gossip_block(32, n, bf16)
+    guarded = [gossip_block(m, n, bf16, guarded=True) for m in (2, 8, 32)]
+    assert guarded == sorted(guarded, reverse=True) and guarded[0] > guarded[2]
+    assert obfuscate_block(4, n, bf16, bf16) == gossip_block(4, n, bf16)
+    assert gossip_block(4, n, bf16) >= 32768
+
+
+def _before_pdsgd(W, B, x_tree, g_tree, bits_tree, lam, mask):
+    """The update as the kernels computed it with their former fixed
+    blocks: pad to 512, obfuscate in (m, 256) tiles, gossip in 512
+    columns."""
+    from repro.kernels import masked_gossip_update
+    from repro.kernels.ops import _flatten_concat, _pad_cols, _unflatten
+    x, sizes, leaves = _flatten_concat(x_tree)
+    g, _, _ = _flatten_concat(g_tree)
+    bits, _, _ = _flatten_concat(bits_tree)
+    (x, pad), (g, _), (bits, _) = (_pad_cols(a, 512) for a in (x, g, bits))
+    u = obfuscate_update(x, g, bits, lam, 0.0, -1.0, block=(x.shape[0], 256))
+    out = (gossip_update(W, B, x, u, block_n=512) if mask is None
+           else masked_gossip_update(mask, B, x, u, block_n=512))
+    return _unflatten(out[:, :x.shape[1] - pad], sizes, leaves, x_tree)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("m", [2, 4, 8])
+def test_update_paths_block_parity(m, masked):
+    """fused_pdsgd_tree (concat) and sharded_pdsgd_tree (leafwise), HBM
+    bits, over a tree whose width is no multiple of the rule's block and
+    spans several of them: bit for bit what the former fixed blocks gave,
+    and the kernels/ref.py oracles up to float32 rounding."""
+    from repro.core.mixing import metropolis_from_mask
+    from repro.kernels import fused_pdsgd_tree
+    from repro.kernels.gossip import gossip_block
+    from repro.kernels.ops import _flatten_concat, sharded_pdsgd_tree
+    shapes = {"emb": (97, 301), "w": (5003,), "b": (9,), "h": (64, 333)}
+    ks = iter(jax.random.split(jax.random.key(m), 16))
+    x = {k: jax.random.normal(next(ks), (m,) + s) for k, s in shapes.items()}
+    g = {k: jax.random.normal(next(ks), (m,) + s) for k, s in shapes.items()}
+    bits = {k: jax.random.bits(next(ks), (m,) + s, jnp.uint32)
+            for k, s in shapes.items()}
+    W = jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32))
+    B = jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32))
+    mask = None
+    if masked:
+        mask = jnp.ones((m, m), jnp.float32) - jnp.eye(m, dtype=jnp.float32)
+        W = metropolis_from_mask(mask)
+    width = _flatten_concat(x)[0].shape[1]
+    bc = gossip_block(m, width, jnp.float32, interpret=True)
+    assert width % bc and width > 2 * bc
+    lam = 0.07
+    before = _before_pdsgd(W, B, x, g, bits, lam, mask)
+    fused = fused_pdsgd_tree(W, B, x, g, bits, lam, mask=mask,
+                             interpret=True)
+    leafwise = sharded_pdsgd_tree(W, B, x, g, bits, lam, mask=mask,
+                                  interpret=True)
+    for k in shapes:
+        assert np.array_equal(np.asarray(fused[k]), np.asarray(before[k])), k
+        assert np.array_equal(np.asarray(leafwise[k]),
+                              np.asarray(before[k])), k
+        u = ref.obfuscate_ref(x[k].reshape(m, -1), g[k].reshape(m, -1),
+                              bits[k].reshape(m, -1), jnp.float32(lam),
+                              0.0, -1.0)
+        expect = ref.gossip_ref(W, B, x[k].reshape(m, -1), u)
+        np.testing.assert_allclose(np.asarray(fused[k]).reshape(m, -1),
+                                   np.asarray(expect), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="the compiled kernels need the chip")
+def test_update_kernels_block_invariant_tpu():
+    """Compiled, each column of the update is a function of that column
+    alone: the rule's wide blocks, the last one overhanging the buffer,
+    give bit for bit what (m, 256) obfuscate tiles and 512-column gossip
+    blocks give."""
+    from repro.kernels.gossip import gossip_block
+    m, n = 4, 3 * 65536 + 7 * 512
+    x, u = _randn((m, n), jnp.bfloat16), _randn((m, n), jnp.bfloat16)
+    bits = jax.random.bits(jax.random.key(2), (m, n), dtype=jnp.uint32)
+    W = jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32))
+    narrow = (obfuscate_update(x, u, bits, 0.05, 0.0, -1.0, block=(m, 256)),
+              gossip_update(W, W, x, u, block_n=512))
+    wide = (obfuscate_update(x, u, bits, 0.05, 0.0, -1.0),
+            gossip_update(W, W, x, u))
+    assert gossip_block(m, n, jnp.bfloat16) == 65536
+    for a, b in zip(narrow, wide):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 # -- in-kernel TPU randomness (the kernels.runtime knob) ------------------
 
 
