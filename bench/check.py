@@ -64,11 +64,20 @@ def _leaf_norms(tree):
                       for l in jax.tree.leaves(tree)])
 
 
+def without_agent_axis(shardings):
+    """Per leaf, the sharding of one agent's slice of a leaf that
+    ``shardings`` (`NamedSharding`s, agent axis first) places."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.tree.map(
+        lambda s: NamedSharding(s.mesh, PartitionSpec(*s.spec[1:])),
+        shardings)
+
+
 def reference_chunk(ref, sizes: dict, x0, chunk: dict, step_keys, *,
                     m: int, algorithm: str, lr: float, hold: int,
                     seed: int, mode: str = "f32", draws: str = "ref",
                     loss_fn=None, live: int | None = None,
-                    update_scale=None) -> dict:
+                    update_scale=None, shardings=None) -> dict:
     """Walk K steps of the reference from the unstacked weights x0 on
     ``chunk`` ({"tokens", "labels"}: (K, m, B, S)) with the program's step
     keys ``step_keys`` ((K,) keys).  Returns the agent-mean loss and the
@@ -77,34 +86,74 @@ def reference_chunk(ref, sizes: dict, x0, chunk: dict, step_keys, *,
     stream of Lambda ("ref" or "ctl").  ``loss_fn``, ``live`` (only agents
     [0, live) have data: the others get no gradient and no loss) and
     ``update_scale`` (per-leaf factors on the update) let a test plant a
-    fault in the reference."""
+    fault in the reference.
+
+    ``shardings`` (the program's `NamedSharding` of each parameter leaf,
+    agent axis first) spreads the walk over the program's mesh, for a
+    model whose reference does not fit one chip.  The mathematics is the
+    same; three things differ from the walk on one device:
+
+    * the stacked parameters and gradients live sharded as the program's,
+      and every agent's float32 weights and gradient inside one jitted
+      ``vmap`` over the agents, which GSPMD splits over the mesh (one
+      agent after the other, on one device, without it);
+    * each layer of ``ref.loss`` is rematerialized (``remat=True``): its
+      activations are recomputed in the backward pass, not kept;
+    * the update is one jitted call, so that Lambda is drawn in place on
+      each chip (the generator's draws do not depend on the sharding).
+    """
     from .seeds import jax_key
     store = jnp.dtype(sizes["dtype"])
     support = U.ring_support(m)
     W = U.metropolis(support)
     lam_key = jax_key(seed, draws + "_lambda")
-    loss_fn = loss_fn or partial(ref.loss, s=sizes, mode=mode)
     live = m if live is None else live
-    vg = jax.jit(jax.value_and_grad(loss_fn))
-    x = jax.tree.map(lambda a: jnp.broadcast_to(a, (m,) + a.shape), x0)
-    K = chunk["tokens"].shape[0]
-    losses, consensus, g0 = [], [], None
-    with jax.default_matmul_precision("highest"):
-        for k in range(K):
+    if shardings is None:
+        loss_fn = loss_fn or partial(ref.loss, s=sizes, mode=mode)
+        vg = jax.jit(jax.value_and_grad(loss_fn))
+        x = jax.tree.map(lambda a: jnp.broadcast_to(a, (m,) + a.shape), x0)
+        pdsgd, dsgd = U.pdsgd, U.dsgd
+
+        def grads(x, batch):
             ls, gs = [], []
             for a in range(m):
                 # float32 weights, so that the gradient is float32 too
                 xa = jax.tree.map(lambda t: t[a].astype(F32), x)
                 if a < live:
-                    batch = {n: jnp.asarray(v[k, a])
-                             for n, v in chunk.items()}
-                    l, g = vg(xa, batch)
+                    l, g = vg(xa, {n: jnp.asarray(v[a])
+                                   for n, v in batch.items()})
                     ls.append(float(l))
                 else:
                     g = jax.tree.map(jnp.zeros_like, xa)
                 gs.append(g)
-            g = jax.tree.map(lambda *t: jnp.stack(t), *gs)
-            del gs
+            return ls, jax.tree.map(lambda *t: jnp.stack(t), *gs)
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec
+        loss_fn = loss_fn or partial(ref.loss, s=sizes, mode=mode,
+                                     remat=True)
+        mesh = jax.tree.leaves(shardings)[0].mesh
+        vg = jax.jit(lambda x, b: jax.vmap(jax.value_and_grad(loss_fn))(
+            jax.tree.map(lambda t: t.astype(F32), x), b),
+            out_shardings=(NamedSharding(mesh, PartitionSpec()), shardings))
+        x = jax.jit(lambda t: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (m,) + a.shape), t),
+            out_shardings=shardings)(x0)
+        pdsgd = jax.jit(U.pdsgd, static_argnums=6, out_shardings=shardings)
+        dsgd = jax.jit(U.dsgd, static_argnums=4, out_shardings=shardings)
+        has_data = (jnp.arange(m) < live).astype(F32)
+
+        def grads(x, batch):
+            l, g = vg(x, {n: jnp.asarray(v) for n, v in batch.items()})
+            if live < m:
+                g = jax.tree.map(lambda t: t * has_data.reshape(
+                    (m,) + (1,) * (t.ndim - 1)), g)
+            return [float(v) for v in np.asarray(l)[:live]], g
+
+    K = chunk["tokens"].shape[0]
+    losses, consensus, g0 = [], [], None
+    with jax.default_matmul_precision("highest"):
+        for k in range(K):
+            ls, g = grads(x, {n: v[k] for n, v in chunk.items()})
             if g0 is None:
                 g0 = np.asarray(_leaf_norms(jax.tree.map(
                     lambda t: t.mean(0), g)))
@@ -112,10 +161,10 @@ def reference_chunk(ref, sizes: dict, x0, chunk: dict, step_keys, *,
             lam = U.step_size(k, lr, hold)
             if algorithm == "pdsgd":
                 B = U.sample_b(step_keys[k], k, support)
-                new = U.pdsgd(x, g, W, B, lam,
-                              jax.random.fold_in(lam_key, k), store)
+                new = pdsgd(x, g, W, B, lam, jax.random.fold_in(lam_key, k),
+                            store)
             elif algorithm == "dsgd":
-                new = U.dsgd(x, g, W, lam, store)
+                new = dsgd(x, g, W, lam, store)
             else:
                 raise ValueError(f"no reference for {algorithm!r}")
             if update_scale is not None:

@@ -18,7 +18,9 @@ comparison's ``ref`` side) and then, each drawing its own Lambda and B:
   reports its losses and consensus errors (change 0); it needs no run.
 
 One JSON line per seed and reading.  The benchmark's own runs never run
-this; it needs the chip only for the cell's sizes.
+this; it needs the chip only for the cell's sizes.  Where the traffic asks
+for a mesh, every walk is spread over it as the program's state is
+(`check.reference_chunk`'s ``shardings``).
 """
 from __future__ import annotations
 
@@ -43,6 +45,7 @@ def readings(cell, seed: int):
 
     from bench import check
     from bench.refs.common import CONTROL_MODE
+    from bench.run import arch_config, make_program
     from bench.seeds import jax_key
     from repro.data import make_lm_pipeline
     from repro.launch.steps import per_step_keys
@@ -55,15 +58,22 @@ def readings(cell, seed: int):
     chunk = make_lm_pipeline(sizes["vocab_size"], m, B, pargs.seq_len,
                              seed=seed).chunk_at(0, K)
     keys = per_step_keys(jax_key(seed, "step_keys"), 0, K)
-    x0 = jax.jit(lambda k: ref.init(k, sizes))(jax_key(seed, "weights"))
+    shardings = None
+    if pargs.mesh_fsdp > 1 or pargs.mesh_tensor > 1:
+        shardings = make_program(arch_config(cell.config),
+                                 pargs).params_shardings
+    x0 = jax.jit(lambda k: ref.init(k, sizes), **({} if shardings is None
+                 else dict(out_shardings=check.without_agent_axis(
+                     shardings))))(jax_key(seed, "weights"))
     walk = partial(check.reference_chunk, ref, sizes, x0, chunk, keys, m=m,
                    algorithm=pargs.algorithm, lr=pargs.lr,
-                   hold=pargs.warmup_hold, seed=seed)
+                   hold=pargs.warmup_hold, seed=seed, shardings=shardings)
     base = walk()
     n = [leaf.size for leaf in jax.tree.leaves(x0)]
     double = jax.tree.unflatten(jax.tree.structure(x0), [
         2.0 if i == int(np.argmax(n)) else 1.0 for i in range(len(n))])
-    half = (dict(loss_fn=lambda p, b: ref.loss(p, halve(b), sizes))
+    half = (dict(loss_fn=lambda p, b: ref.loss(
+        p, halve(b), sizes, remat=shardings is not None))
             if B > 1 else dict(live=m // 2))
     out = {
         "sound": walk(draws="ctl"),

@@ -101,15 +101,19 @@ def _experts(p, h, s, mode):
     return jnp.einsum("bsed,bse->bsd", y, weight)
 
 
-def loss(params, batch, s: dict, mode: str = "f32"):
+def loss(params, batch, s: dict, mode: str = "f32", remat: bool = False):
     """Mean next-token cross-entropy of one agent's batch
-    ({"tokens", "labels"}: (B, S) int32)."""
+    ({"tokens", "labels"}: (B, S) int32).  ``remat`` recomputes each
+    layer's activations in the backward pass instead of keeping them."""
+    def layer(x, p):
+        x = x + _attention(p, rms_norm(x, p["attn_norm_gamma"]), s, mode)
+        return x + _experts(p["moe"], rms_norm(x, p["mlp_norm_gamma"]), s,
+                            mode)
+
+    layer = jax.checkpoint(layer) if remat else layer
     x = params["embed"].astype(F32)[batch["tokens"]]
     for i in range(s["num_layers"]):
-        p = jax.tree.map(lambda a: a[i], params["layers"])
-        x = x + _attention(p, rms_norm(x, p["attn_norm_gamma"]), s, mode)
-        x = x + _experts(p["moe"], rms_norm(x, p["mlp_norm_gamma"]), s,
-                         mode)
+        x = layer(x, jax.tree.map(lambda a: a[i], params["layers"]))
     x = rms_norm(x, params["final_norm_gamma"])
     logits = mm("bsd,vd->bsv", x, params["embed"][:s["vocab_size"]], mode)
     return cross_entropy(logits, batch["labels"])

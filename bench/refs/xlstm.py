@@ -116,16 +116,21 @@ def _slstm(p, x, H, mode):
     return x + mm("bsd,de->bse", y, p["w_down"], mode)
 
 
-def loss(params, batch, s: dict, mode: str = "f32"):
+def loss(params, batch, s: dict, mode: str = "f32", remat: bool = False):
     """Mean next-token cross-entropy of one agent's batch
-    ({"tokens", "labels"}: (B, S) int32)."""
+    ({"tokens", "labels"}: (B, S) int32).  ``remat`` recomputes each
+    block's activations in the backward pass instead of keeping them."""
     H, V = s["num_heads"], s["vocab_size"]
+    block = {k: (lambda p, x, f=f: f(p, x, H, mode))
+             for k, f in (("mlstm", _mlstm), ("slstm", _slstm))}
+    if remat:
+        block = {k: jax.checkpoint(f) for k, f in block.items()}
     x = params["embed"].astype(F32)[batch["tokens"]]
     seen = {"mlstm": 0, "slstm": 0}
     for kind in _kinds(s):
         p = jax.tree.map(lambda a, i=seen[kind]: a[i], params[kind])
         seen[kind] += 1
-        x = (_mlstm if kind == "mlstm" else _slstm)(p, x, H, mode)
+        x = block[kind](p, x)
     x = rms_norm(x, params["final_norm_gamma"])
     logits = mm("bsd,vd->bsv", x, params["embed"][:V], mode)
     return cross_entropy(logits, batch["labels"])

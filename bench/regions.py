@@ -14,15 +14,21 @@ share a name):
 * `region_of` turns one ``op_name`` into a region path.  Work of the model
   splits into ``step.model/fwd`` and ``step.model/bwd`` by JAX's own
   ``transpose(...)`` wrapper; a rematerialized forward is backward work.
-* `instruction_regions` maps every instruction of a program's text.
-* `load` reads what `bench.trace.load` leaves out: the ``XLA Modules``
-  intervals of each device and the ``repro.*`` host spans with their stats.
+* `instruction_regions` maps every instruction of a program's text;
+  `bench.run` puts that map and the step module's name in the readers'
+  context.
 * `region_ns` sums each region's own device time (`bench.trace.
-  self_times`); time of the step module under no region is ``unscoped``.
-* `data_produce_ms` reads the data spans of the chunks a window consumed.
+  self_times`) inside the step module's runs (`bench.trace.load` keeps
+  the ``XLA Modules`` intervals); time of the step module under no region
+  is ``unscoped``.
+* `window_regions` does that once for a traced window, over the cell's
+  chips, and `reader_ms` gives the readers of ``bench/metrics/`` their
+  number from it.
+* `data_produce_ms` reads the data spans (``repro.data.*``) of the chunks
+  a window consumed.
 
 Run as a script, it drives one traced run of a cell through `bench.run`
-unchanged and prints what the per-layer readers would read from regions:
+unchanged and prints every region, not only those the readers report:
 
     python3 bench/regions.py --workload <cell> --seed <n> --seconds <s>
 
@@ -37,7 +43,6 @@ builds.  The last line is `bench.run`'s result.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import re
 import sys
@@ -45,8 +50,6 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 UNSCOPED = "unscoped"
-MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "repro."
 # the tables of source locations that `as_text()` prints before the
 # computations; like metadata, they change with any edit of the source
 _DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations",
@@ -146,37 +149,6 @@ def strip_metadata(hlo_text: str) -> str:
     return "\n".join(out) + "\n"
 
 
-@dataclasses.dataclass
-class Program:
-    # device plane name -> [(module name, start ns, end ns)]
-    modules: dict
-    # [(span name, host line, start ns, end ns, {stat: value})]
-    spans: list
-
-
-def load(path: str, span_prefix: str = SPAN_PREFIX) -> Program:
-    """The module intervals and the program's host spans of an
-    ``.xplane.pb`` (`bench.trace.load` keeps the operations)."""
-    from jax.profiler import ProfileData
-
-    from bench import trace as T
-    pd = ProfileData.from_file(path)
-    modules, spans = {}, []
-    for plane in pd.planes:
-        if T._is_device_plane(plane.name):
-            mods = [(e.name, int(e.start_ns), int(e.end_ns))
-                    for line in plane.lines if line.name == MODULES_LINE
-                    for e in line.events]
-            if mods:
-                modules[plane.name] = sorted(mods, key=lambda m: m[1])
-        elif plane.name.startswith("/host:"):
-            spans += [(e.name, line.name, int(e.start_ns), int(e.end_ns),
-                       dict(e.stats))
-                      for line in plane.lines for e in line.events
-                      if e.name.startswith(span_prefix)]
-    return Program(modules, sorted(spans, key=lambda s: s[2]))
-
-
 def module_intervals(modules, name: str):
     """(start, end) of each run of the module ``name``: a trace names a
     run ``<module>(<program id>)``."""
@@ -233,8 +205,69 @@ def summary(region: dict, per: float) -> dict:
             "update_layout_ms": ms(rt.STEP_UPDATE + "/" + rt.LAYOUT)}
 
 
+def window_regions(ctx) -> dict:
+    """The step module's operations in the traced window of a run's
+    readers' context (`bench.run`), once per context: ``region`` ({path
+    or ``unscoped``: own ns}, summed over the cell's chips), ``busy`` (the
+    module's busy ns, summed) and ``stray`` ({instruction: own ns} under
+    no region)."""
+    if "window_regions" not in ctx:
+        from bench import trace as T
+        reduced, lo, hi = ctx["trace"], *ctx["trace_window"]
+        region, busy, stray = {}, 0, {}
+        for plane in ctx["planes"]:
+            ops = inside(reduced.device_ops[plane], module_intervals(
+                reduced.modules.get(plane, []), ctx["module"]), lo, hi)
+            for k, v in region_ns(ops, [(lo, hi)], ctx["regions"], lo,
+                                  hi).items():
+                region[k] = region.get(k, 0) + v
+            busy += T.busy_ns(ops, lo, hi)
+            for name, ns in T.self_times(ops):
+                if not ctx["regions"].get(name):
+                    stray[name] = stray.get(name, 0) + ns
+        ctx["window_regions"] = {"region": region, "busy": busy,
+                                 "stray": stray}
+    return ctx["window_regions"]
+
+
+def reader_ms(ctx, name: str):
+    """``name`` of `summary` (ms per step and chip) for a run's readers'
+    context; None where the window has no step, no device plane, or no
+    time in that region."""
+    if ctx["trace"] is None or not ctx["planes"] or not ctx["steps"]:
+        return None
+    v = summary(window_regions(ctx)["region"],
+                ctx["steps"] * len(ctx["planes"]))[name]
+    return v or None
+
+
+def regions_line(ctx, update_kernel_ms=None) -> dict:
+    """The ``phase="regions"`` line of a traced window: ms per step and
+    chip of every region of the step module and of its busy time (only
+    the module's name where the trace has no device plane)."""
+    steps, planes = ctx["steps"], ctx["planes"]
+    if not planes or not steps:
+        return {"module": ctx["module"], "steps": steps, "ms": None}
+    w = window_regions(ctx)
+    per = steps * len(planes)
+    ms = {k: 1e-6 * v / per for k, v in sorted(w["region"].items())}
+    rt = _names()
+    kernels = sum(v for k, v in ms.items() if k in (
+        rt.STEP_UPDATE + "/" + rt.OBFUSCATE, rt.STEP_UPDATE + "/" + rt.GOSSIP))
+    return {"module": ctx["module"], "steps": steps, "ms": ms,
+            "sum_ms": sum(ms.values()),
+            "module_busy_ms": 1e-6 * w["busy"] / per,
+            "obfuscate_gossip_ms": kernels,
+            "update_kernel_ms": update_kernel_ms,
+            **summary(w["region"], per),
+            "data_produce_ms": data_produce_ms(
+                ctx["trace"].program_spans, *ctx["trace_window"]),
+            "unscoped_top": [[k, 1e-6 * v / per] for k, v in sorted(
+                w["stray"].items(), key=lambda kv: -kv[1])[:8]]}
+
+
 # ---------------------------------------------------------------------------
-# One traced run of a cell, observed at three seams of `bench.run`
+# One traced run of a cell
 # ---------------------------------------------------------------------------
 
 def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
@@ -252,34 +285,21 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     import jax
 
     from bench import run as R
-    from bench import trace as T
     seen, memory = {}, {}
-
-    def kernel_calls(text):        # the harness hands it the step's text
-        seen["hlo"] = text
-        return real_calls(text)
-
-    def trace_load(path, *a, **kw):  # ... and the trace, before deleting it
-        seen["program"] = load(path)
-        seen["reduced"] = real_load(path, *a, **kw)
-        return seen["reduced"]
 
     def listen(fn, real, when):    # the window opens and closes with these
         memory[when] = [d.memory_stats() or {} for d in jax.local_devices()]
         return real(fn)
 
-    real_calls, real_load = R.kernel_calls, T.load
     mon = jax.monitoring
     reg, unreg = (mon.register_event_listener,
                   mon.unregister_event_listener)
-    with mock.patch.object(R, "kernel_calls", kernel_calls), \
-            mock.patch.object(T, "load", trace_load), \
-            mock.patch.object(mon, "register_event_listener",
-                              lambda fn: listen(fn, reg, "before")), \
+    with mock.patch.object(mon, "register_event_listener",
+                           lambda fn: listen(fn, reg, "before")), \
             mock.patch.object(mon, "unregister_event_listener",
                               lambda fn: listen(fn, unreg, "after")):
         try:
-            result = R.run(args, root, require_tpu)
+            result = R.run(args, root, require_tpu, keep=seen)
         except R.Fail as e:
             print(f"regions: {e}", file=sys.stderr)
             return 1
@@ -287,49 +307,11 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
     if args.hlo_out:
         Path(args.hlo_out).write_text(strip_metadata(seen["hlo"]))
     if args.trace:
-        chips = R.find_cell(root, args.workload).workload["chips"]
         R.note(phase="regions", **regions_line(
-            seen["hlo"], seen["reduced"], seen["program"],
-            result["attempted"], chips,
+            seen["ctx"],
             result["metrics"].get("update_kernel_ms", {}).get("value")))
     print(json.dumps(result), flush=True)
     return 0
-
-
-def regions_line(hlo_text: str, reduced, program: Program, steps: int,
-                 chips: int, update_kernel_ms=None) -> dict:
-    """The ``phase="regions"`` line of a traced window: ms per step and
-    chip of every region of the step module and of its busy time (only
-    the module's name where the trace has no device plane)."""
-    from bench import trace as T
-    module, regions = instruction_regions(hlo_text)
-    lo, hi = reduced.window()
-    planes = sorted(reduced.device_ops)[:chips]
-    if not planes or not steps:
-        return {"module": module, "steps": steps, "ms": None}
-    total, busy, stray = {}, 0, {}
-    for plane in planes:
-        ops = inside(reduced.device_ops[plane], module_intervals(
-            program.modules.get(plane, []), module), lo, hi)
-        for k, v in region_ns(ops, [(lo, hi)], regions, lo, hi).items():
-            total[k] = total.get(k, 0) + v
-        busy += T.busy_ns(ops, lo, hi)
-        for name, ns in T.self_times(ops):
-            if not regions.get(name):
-                stray[name] = stray.get(name, 0) + ns
-    per = steps * len(planes)
-    ms = {k: 1e-6 * v / per for k, v in sorted(total.items())}
-    rt = _names()
-    kernels = sum(v for k, v in ms.items() if k in (
-        rt.STEP_UPDATE + "/" + rt.OBFUSCATE, rt.STEP_UPDATE + "/" + rt.GOSSIP))
-    return {"module": module, "steps": steps, "ms": ms,
-            "sum_ms": sum(ms.values()), "module_busy_ms": 1e-6 * busy / per,
-            "obfuscate_gossip_ms": kernels,
-            "update_kernel_ms": update_kernel_ms,
-            **summary(total, per),
-            "data_produce_ms": data_produce_ms(program.spans, lo, hi),
-            "unscoped_top": [[k, 1e-6 * v / per] for k, v in sorted(
-                stray.items(), key=lambda kv: -kv[1])[:8]]}
 
 
 if __name__ == "__main__":

@@ -9,7 +9,8 @@ All are found by name.  The run builds the program's own decentralized
 step (`core.make_decentralized_step`, `make_scanned_steps`, per-step keys
 from `launch.steps.per_step_keys`, the program's synthetic token stream
 `data.make_lm_pipeline` through `data.prefetch_chunks`) with the settings
-`launch.train.build_parser` gives the traffic's flags,
+`launch.train.build_parser` gives the traffic's flags, on one chip or, where
+the flags ask for a mesh, over the cell's chips (`make_program`),
 makes the weights on the device from ``--seed``, compiles the step ahead of
 time, drives its first chunk (kept for the output check), then measures
 for ``--seconds``.  After the window it reads the device's memory, frees
@@ -141,29 +142,85 @@ def arch_config(config: dict):
     return cfg
 
 
-def make_program(cfg, pargs):
-    """(model bundle, scanned K-step program) built as `launch.train.
-    run_training` builds them for the same flags on one device."""
-    from repro.core import make_decentralized_step, make_scanned_steps
+@dataclasses.dataclass
+class Program:
+    bundle: object           # the model (`models.build_model`)
+    scanned: object          # the scanned K-step program
+    mesh: object             # None on one device
+    params_shardings: object  # per parameter leaf, agent axis first; or None
+    state_shardings: object  # of the whole state; or None
+    place: object            # puts a chunk where the step reads it
+
+
+def make_program(cfg, pargs) -> Program:
+    """The program as `launch.train.run_training` builds it for the same
+    flags: on one device, or, where they ask for ``--mesh-fsdp`` or
+    ``--mesh-tensor`` above 1, over `launch.mesh.make_sharded_mesh`'s
+    mesh, with the program's sharding audit, leaf specs and state
+    placement (`optim.shard_like`) and the step told the mesh.  The
+    layout ``auto`` is ``leafwise`` on a mesh and ``concat`` on one
+    device; ``ring`` does not compose with a mesh."""
+    import jax
+    from repro.core import (init_state, make_decentralized_step,
+                            make_scanned_steps)
     from repro.core.schedules import warmup_harmonic
+    from repro.data import make_placer
     from repro.launch.train import build_faults, build_mixing
     from repro.models import build_model
-    if pargs.mesh_fsdp > 1 or pargs.mesh_tensor > 1:
-        raise Fail("sharded traffic needs a mesh; not built here")
-    bundle = build_model(cfg)
+    sharded = pargs.mesh_fsdp > 1 or pargs.mesh_tensor > 1
     layout, use_pallas = pargs.kernel_layout, None
     if layout == "auto":
-        layout = "concat"
+        layout = "leafwise" if sharded else "concat"
     elif layout == "ring":
+        if sharded:
+            raise Fail("--kernel-layout ring flattens each agent's leaves; "
+                       "it does not compose with --mesh-fsdp/--mesh-tensor")
         use_pallas = True
+    mesh = leaf_specs = params_sh = state_sh = None
+    if sharded:
+        from repro.launch.mesh import make_sharded_mesh
+        try:
+            mesh = make_sharded_mesh(agents=pargs.agents,
+                                     fsdp=pargs.mesh_fsdp,
+                                     tensor=pargs.mesh_tensor)
+        except ValueError as e:
+            raise Fail(f"the traffic's mesh: {e}") from None
+    bundle = build_model(cfg, mesh=mesh)
+    if sharded:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.dist.sharding import TRAIN_RULES, audit_rules, \
+            logical_spec
+        from repro.launch.specs import with_agent_axis
+        from repro.optim import shard_like
+        errors = [f for f in audit_rules(bundle.abstract(),
+                                         bundle.logical_axes(), mesh)
+                  if f["severity"] == "error"]
+        if errors:
+            raise Fail("sharding audit failed (unknown logical axes): "
+                       + "; ".join(f"{f['path']}: {f['issue']}"
+                                   for f in errors))
+        p_abs, p_log = with_agent_axis(bundle.abstract(),
+                                       bundle.logical_axes(), pargs.agents)
+        leaf_specs = jax.tree.map(
+            lambda a, log: logical_spec(mesh, a.shape, log, TRAIN_RULES),
+            p_abs, p_log)
+        params_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 leaf_specs)
+        state = jax.eval_shape(lambda p: init_state(
+            p, pargs.agents, algorithm=pargs.algorithm), bundle.abstract())
+        state_sh = shard_like(state, state.params, params_sh,
+                              scalar_sharding=NamedSharding(
+                                  mesh, PartitionSpec()))
     step = make_decentralized_step(
         bundle.loss_fn, build_mixing(pargs),
         warmup_harmonic(pargs.lr, hold=pargs.warmup_hold),
         algorithm=pargs.algorithm, sigma_dp=pargs.sigma_dp,
         grad_clip=pargs.grad_clip_kappa, faults=build_faults(pargs),
         nan_policy=pargs.nan_policy, use_pallas=use_pallas,
-        kernel_layout=layout)
-    return bundle, make_scanned_steps(step, pargs.unroll_k)
+        spmd_axis_name="data" if sharded else None, kernel_layout=layout,
+        mesh=mesh, leaf_specs=leaf_specs)
+    return Program(bundle, make_scanned_steps(step, pargs.unroll_k), mesh,
+                   params_sh, state_sh, make_placer(mesh))
 
 
 def _listed(chunk: dict) -> dict:
@@ -208,7 +265,11 @@ class Spans:
 # One run
 # ---------------------------------------------------------------------------
 
-def run(args, root: Path, require_tpu: bool) -> dict:
+def run(args, root: Path, require_tpu: bool, keep: dict | None = None
+        ) -> dict:
+    """One run of the cell ``args.workload``: the result line.  Where
+    ``keep`` is given, the compiled step's text (``hlo``) and, in a traced
+    run, the readers' context (``ctx``) are left in it."""
     cell = find_cell(root, args.workload)
     if not (root / "src" / "repro").is_dir():
         raise Fail(f"no program (src/repro) under {root}")
@@ -233,7 +294,7 @@ def run(args, root: Path, require_tpu: bool) -> dict:
     from bench import check, counts, peaks
     from bench.seeds import jax_key
     from repro.core import init_state
-    from repro.data import make_lm_pipeline, make_placer, prefetch_chunks
+    from repro.data import make_lm_pipeline, prefetch_chunks
     from repro.launch.compile_cache import use_compile_cache
     from repro.launch.steps import per_step_keys
     from repro.launch.train import build_parser
@@ -248,7 +309,12 @@ def run(args, root: Path, require_tpu: bool) -> dict:
     pargs = build_parser().parse_args(cell.traffic["flags"])
     m, K = pargs.agents, pargs.unroll_k
     B, S = pargs.per_agent_batch, pargs.seq_len
-    bundle, scanned = make_program(cfg, pargs)
+    prog = make_program(cfg, pargs)
+    if prog.mesh is not None and prog.mesh.devices.size != chips:
+        raise Fail(f"the traffic's mesh {dict(prog.mesh.shape)} spans "
+                   f"{prog.mesh.devices.size} devices, the cell {chips} "
+                   "chips")
+    bundle, scanned = prog.bundle, prog.scanned
 
     wkey = jax_key(args.seed, "weights")
     shapes = jax.eval_shape(lambda: ref.init(wkey, sizes))
@@ -260,10 +326,23 @@ def run(args, root: Path, require_tpu: bool) -> dict:
     D = counts.params_per_agent(want)
     itemsize = jnp.dtype(cfg.dtype).itemsize
 
+    def placed(shardings):
+        # on a mesh, each chip makes only its own shards
+        return {} if shardings is None else {"out_shardings": shardings}
+
     # the key is an argument, not a constant: one program for every seed
-    weights = jax.jit(lambda k: ref.init(k, sizes))
+    weights = jax.jit(lambda k: ref.init(k, sizes), **placed(
+        check.without_agent_axis(prog.params_shardings)))
     state = jax.jit(lambda k: init_state(ref.init(k, sizes), m,
-                                         algorithm=pargs.algorithm))(wkey)
+                                         algorithm=pargs.algorithm),
+                    **placed(prog.state_shardings))(wkey)
+    if prog.mesh is not None:
+        leaves = jax.tree.leaves(state.params)
+        note(phase="placement", mesh=dict(prog.mesh.shape),
+             leaves=len(leaves), on_every_chip=sum(
+                 {sh.device for sh in x.addressable_shards}
+                 == set(devices[:chips])
+                 and not x.sharding.is_fully_replicated for x in leaves))
     # the tokens are the program's own stream, seeded as the driver seeds it
     pipeline = make_lm_pipeline(cfg.vocab_size, m, B, S, seed=args.seed)
     key = jax_key(args.seed, "step_keys")
@@ -274,7 +353,7 @@ def run(args, root: Path, require_tpu: bool) -> dict:
         if name in COMPILE_EVENTS:
             n_compiles[0] += 1
 
-    with prefetch_chunks(pipeline, K, start_step=0, place=make_placer(None),
+    with prefetch_chunks(pipeline, K, start_step=0, place=prog.place,
                          depth=pargs.prefetch_depth) as chunks:
         # set-up: compile ahead of time, then the first chunk through the
         # window's own call and feed; it is the chunk the check compares
@@ -282,7 +361,10 @@ def run(args, root: Path, require_tpu: bool) -> dict:
         keys = keys0 = per_step_keys(key, 0, K)
         t = time.perf_counter()
         compiled = scanned.lower(state, chunk, keys).compile()
-        calls = kernel_calls(compiled.as_text())
+        hlo = compiled.as_text()
+        calls = kernel_calls(hlo)
+        if keep is not None:
+            keep["hlo"] = hlo
         note(phase="compile", seconds=time.perf_counter() - t,
              compiled_bytes=compiled_bytes(compiled),
              tpu_custom_calls=calls)
@@ -361,7 +443,8 @@ def run(args, root: Path, require_tpu: bool) -> dict:
     refres = check.reference_chunk(
         ref, sizes, weights(wkey), pipeline.chunk_at(0, K), keys0,
         m=m, algorithm=pargs.algorithm, lr=pargs.lr,
-        hold=pargs.warmup_hold, seed=args.seed)
+        hold=pargs.warmup_hold, seed=args.seed,
+        shardings=prog.params_shardings)
     nums = check.numbers(first, refres)
     correct, rows = check.judge(nums, cell.limits)
     note(phase="compare", program=_listed(first), reference=_listed(refres),
@@ -377,19 +460,25 @@ def run(args, root: Path, require_tpu: bool) -> dict:
             metrics[mtr["name"]] = {"value": e2e[mtr["name"]],
                                     "unit": mtr["unit"]}
     else:
+        from bench.regions import instruction_regions
+        module, regions = instruction_regions(hlo)
         ctx = {"window_s": window_s, "steps": len(losses), "tokens": tokens,
                "chips": chips, "agents": m, "params_per_agent": D,
                "itemsize": itemsize, "peaks": peak,
                "flops_per_token": ref.flops_per_token(sizes, S),
                "spans": spans.done, "trace": reduced,
+               "module": module, "regions": regions,
                "counts": counts, "read": lambda n: metric_reader(root, n)(ctx)}
         from bench import trace as T
         lo, hi = reduced.window()
         planes = sorted(reduced.device_ops)[:chips]
+        note(phase="trace", planes=planes, of=sorted(reduced.device_ops))
         busy = [T.busy_ns(reduced.device_ops[p], lo, hi) for p in planes]
         device["busy_s"] = sum(busy) / len(busy) / 1e9 if busy else 0.0
         device["window_s"] = (hi - lo) / 1e9
         ctx.update(trace_window=(lo, hi), planes=planes)
+        if keep is not None:
+            keep["ctx"] = ctx
         for mtr in cell.per_layer:
             v = metric_reader(root, mtr["name"])(ctx)
             if v is not None:
@@ -419,6 +508,8 @@ def main(argv=None, root: Path = ROOT, require_tpu: bool = True) -> int:
         result = run(args, root, require_tpu)
     except Fail as e:
         print(f"bench: {e}", file=sys.stderr)
+        # the last line of standard output is never a result, nor a note
+        print(f"bench: no result: {e}", flush=True)
         return 1
     print(json.dumps(result), flush=True)
     return 0

@@ -41,7 +41,8 @@ def main(out: Path) -> int:
     cfg = get_config("granite-moe-1b-a400m-tiny")
     pargs = build_parser().parse_args(FLAGS)
     K = pargs.unroll_k
-    bundle, scanned = R.make_program(cfg, pargs)
+    prog = R.make_program(cfg, pargs)
+    bundle, scanned = prog.bundle, prog.scanned
     state = init_state(bundle.init(jax.random.key(0)), pargs.agents)
     pipe = make_lm_pipeline(cfg.vocab_size, pargs.agents,
                             pargs.per_agent_batch, pargs.seq_len, seed=0)

@@ -94,3 +94,12 @@ def test_peaks_refuse_an_unknown_chip():
     assert peaks.peaks("TPU v5 lite")["flops_bf16"] == 197e12
     with pytest.raises(KeyError):
         peaks.peaks("TPU v4")
+
+
+def test_update_yardstick_counts_each_chips_share():
+    D = 211_393_536
+    one = 3 * 4 * D * 2 / 819e9
+    assert counts.update_min_seconds(4, D, 2, 819e9, chips=1) == one
+    assert counts.update_min_seconds(4, D, 2, 819e9) == one
+    assert counts.update_min_seconds(4, D, 2, 819e9, chips=4) == one / 4
+    assert counts.update_min_bytes(2, D, 2, chips=4) == 3 * 2 * D * 2 / 4

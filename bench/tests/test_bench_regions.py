@@ -55,7 +55,8 @@ def _program(which: str, tmp: Path):
         flags = ["--agents", "4", "--unroll-k", "2", "--per-agent-batch",
                  "1", "--seq-len", "16"]
     pargs = build_parser().parse_args(flags)
-    bundle, scanned = R.make_program(cfg, pargs)
+    prog = R.make_program(cfg, pargs)
+    bundle, scanned = prog.bundle, prog.scanned
     state = jax.eval_shape(lambda p: init_state(p, pargs.agents),
                            bundle.abstract())
     pipe = make_lm_pipeline(cfg.vocab_size, pargs.agents,
@@ -271,20 +272,19 @@ def test_prefetch_spans_carry_the_chunks_step():
                 taken = [c["tokens"].shape for c in chunks]
         finally:
             jax.profiler.stop_trace()
-        path = T.find_xplane(d)
-        prog = G.load(path)
-        names = [s[0] for s in T.load(path, span_prefix="repro.data.")
-                 .host_spans]
+        reduced = T.load(T.find_xplane(d), span_prefix="repro.data.")
+        names = [s[0] for s in reduced.host_spans]
+        spans = reduced.program_spans
     assert taken == [(4, 2, 1, 8)] * 3
-    steps = lambda name: sorted(st["step"] for n, _, _, _, st in prog.spans
+    steps = lambda name: sorted(st["step"] for n, _, _, _, st in spans
                                 if n == name)
     assert steps("repro.data.produce")[:3] == [8, 12, 16]
     assert steps("repro.data.place") == [8, 12, 16]
     assert steps("repro.data.wait")[:3] == [8, 12, 16]
     assert names.count("repro.data.place") == 3
-    lo = min(s for *_, s, e, st in prog.spans)
-    hi = max(e for *_, s, e, st in prog.spans)
-    assert G.data_produce_ms(prog.spans, lo, hi) > 0
+    lo = min(s for *_, s, e, st in spans)
+    hi = max(e for *_, s, e, st in spans)
+    assert G.data_produce_ms(spans, lo, hi) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -293,16 +293,21 @@ def test_prefetch_spans_carry_the_chunks_step():
 
 @pytest.fixture(scope="module")
 def chip(tmp_path_factory):
+    """The readers' context (`bench.run`) of the recorded window."""
     path = tmp_path_factory.mktemp("chip") / "regions_chip.xplane.pb"
     path.write_bytes(gzip.decompress(CHIP_TRACE.read_bytes()))
-    return (T.load(str(path)), G.load(str(path)),
-            gzip.decompress(CHIP_HLO.read_bytes()).decode())
+    reduced = T.load(str(path))
+    module, regions = G.instruction_regions(
+        gzip.decompress(CHIP_HLO.read_bytes()).decode())
+    return {"trace": reduced, "trace_window": reduced.window(),
+            "planes": sorted(reduced.device_ops)[:1], "steps": 10,
+            "module": module, "regions": regions}
 
 
 def test_chip_regions_add_up_to_the_step_modules_busy_time(chip):
-    reduced, prog, text = chip
+    reduced = chip["trace"]
     (plane,) = reduced.device_ops
-    line = G.regions_line(text, reduced, prog, steps=10, chips=1)
+    line = G.regions_line(dict(chip))
     assert line["module"] == "jit_scanned"
     # equal up to the trace's rounding to whole nanoseconds
     assert line["sum_ms"] == pytest.approx(line["module_busy_ms"], rel=1e-5)
@@ -317,6 +322,17 @@ def test_chip_regions_add_up_to_the_step_modules_busy_time(chip):
     kernels = sum(e - s for _, s, e in T.matching(
         T.clip(reduced.device_ops[plane], lo, hi), ("obfuscate", "gossip")))
     assert kernels / 1e6 / 10 <= line["obfuscate_gossip_ms"] * (1 + 1e-9)
+
+
+@pytest.mark.parametrize("name", ["model_fwd_ms", "model_bwd_ms",
+                                  "update_layout_ms", "data_produce_ms"])
+def test_readers_give_what_the_regions_line_prints(chip, name):
+    from bench.run import ROOT, metric_reader
+    line = G.regions_line(dict(chip))
+    assert metric_reader(ROOT, name)(dict(chip)) == line[name] > 0
+    # no trace, or no device plane: nothing to read, never 0
+    assert metric_reader(ROOT, name)(dict(chip, planes=[], trace=None)) \
+        is None
 
 
 def test_regions_tool_drives_the_harness(tmp_path):

@@ -1,8 +1,10 @@
 """Reduce a JAX profiler trace to what the per-layer metrics read.
 
 `load` reads an ``.xplane.pb`` with `jax.profiler.ProfileData` and keeps
-two things: the device operations of each device plane (name, start, end
-in ns) and the harness's own host spans (``bench.*`` trace annotations).
+the device operations of each device plane (name, start, end in ns), the
+runs of each compiled program on it (``XLA Modules``), the harness's own
+host spans (``bench.*`` trace annotations) and the program's
+(``repro.*``, `repro.trace`, with their stats).
 The rest are plain functions over those intervals: the union of busy
 time, the idle gaps between operations, the host span that was open
 during each gap, operations matched by name, and the operations that
@@ -15,7 +17,9 @@ import glob
 import os
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 @dataclasses.dataclass
@@ -24,6 +28,11 @@ class Reduced:
     device_ops: dict
     # [(span name, start ns, end ns)] of the harness's host annotations
     host_spans: list
+    # device plane name -> [(module name, start ns, end ns)], by start
+    modules: dict
+    # [(span name, host line, start ns, end ns, {stat: value})] of the
+    # program's host spans, by start
+    program_spans: list
 
     def window(self, name: str = SPAN_PREFIX + "window"):
         """(start, end) ns of the host span ``name``; None if absent."""
@@ -55,22 +64,37 @@ def op_name(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].strip().lstrip("%")
 
 
+def _events(plane, line_name: str):
+    return sorted(((e.name, int(e.start_ns), int(e.end_ns))
+                   for line in plane.lines if line.name == line_name
+                   for e in line.events), key=lambda o: o[1])
+
+
 def load(path: str, span_prefix: str = SPAN_PREFIX) -> Reduced:
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    device_ops, host_spans = {}, []
+    device_ops, modules, host_spans, program_spans = {}, {}, [], []
     for plane in pd.planes:
         if _is_device_plane(plane.name):
-            ops = [(op_name(e.name), int(e.start_ns), int(e.end_ns))
-                   for line in plane.lines if line.name == OPS_LINE
-                   for e in line.events]
+            ops = [(op_name(n), s, e) for n, s, e in _events(plane,
+                                                              OPS_LINE)]
             if ops:
-                device_ops[plane.name] = sorted(ops, key=lambda o: o[1])
+                device_ops[plane.name] = ops
+            mods = _events(plane, MODULES_LINE)
+            if mods:
+                modules[plane.name] = mods
         elif plane.name.startswith("/host:"):
-            host_spans += [(e.name, int(e.start_ns), int(e.end_ns))
-                           for line in plane.lines for e in line.events
-                           if e.name.startswith(span_prefix)]
-    return Reduced(device_ops, sorted(host_spans, key=lambda s: s[1]))
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(span_prefix):
+                        host_spans.append((e.name, int(e.start_ns),
+                                           int(e.end_ns)))
+                    if e.name.startswith(PROGRAM_PREFIX):
+                        program_spans.append(
+                            (e.name, line.name, int(e.start_ns),
+                             int(e.end_ns), dict(e.stats)))
+    return Reduced(device_ops, sorted(host_spans, key=lambda s: s[1]),
+                   modules, sorted(program_spans, key=lambda s: s[2]))
 
 
 def clip(ops, lo: int, hi: int):
