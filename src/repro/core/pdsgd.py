@@ -72,13 +72,39 @@ def replicate_params(params: Pytree, m: int) -> Pytree:
     return jax.tree.map(lambda p: jnp.broadcast_to(p[None], (m,) + p.shape), params)
 
 
-def consensus_error(params: Pytree) -> jax.Array:
-    """sum_i ||x_i - x_bar||^2 — the disagreement Lyapunov term of Thm 1."""
+def consensus_error(params: Pytree, stored: bool = False) -> jax.Array:
+    """sum_i ||x_i - x_bar||^2 — the disagreement Lyapunov term of Thm 1.
+
+    ``stored=True`` measures the values the parameters' dtype holds: each
+    leaf and its agent mean are rounded to that dtype with
+    `jax.lax.reduce_precision`, and the squares are summed in float32.
+    Inside a jitted step the compiler may hand this report the new
+    parameters before they are rounded to their dtype (XLA's excess
+    precision), and a convert back and forth does not stop it.  On a TPU
+    mesh the report read three times the stored parameters' spread, as
+    the unrounded update would give: most of a small update rounds away
+    in the stored parameters (PERF.md).
+    """
     def leaf(p):
+        if stored:
+            q = _as_stored(p.astype(jnp.float32), p.dtype)
+            mean = _as_stored(q.mean(axis=0, keepdims=True), p.dtype)
+            return jnp.sum(jnp.square(q - mean))
         mean = p.mean(axis=0, keepdims=True)
         return jnp.sum((p - mean) ** 2)
 
     return sum(jax.tree.leaves(jax.tree.map(leaf, params)))
+
+
+def _as_stored(x: jax.Array, dtype) -> jax.Array:
+    """float32 ``x`` rounded to the values ``dtype`` holds (nearest even),
+    for bfloat16 or float32 parameters: bfloat16 keeps float32's exponent,
+    so no value is flushed, as it would be for float16's subnormals."""
+    f = jnp.finfo(dtype)
+    if f.bits >= 32:
+        return x
+    return jax.lax.reduce_precision(x, exponent_bits=f.nexp,
+                                    mantissa_bits=f.nmant)
 
 
 def gossip_mix(mat: jax.Array, params: Pytree) -> Pytree:
@@ -177,8 +203,8 @@ def pdsgd_update(
     (default) is the single flattened (m, ΣD) pass; ``"leafwise"`` is
     `kernels.sharded_pdsgd_tree` — per-leaf kernels, bit-identical to
     concat, that keep FSDP/tensor-sharded leaves sharded (with ``mesh``
-    + ``leaf_specs`` the obfuscate kernel runs per shard under shard_map
-    and the gossip contraction stays a GSPMD einsum).  The leafwise
+    + ``leaf_specs`` the obfuscate kernel's math is an XLA fusion on each
+    sharded leaf and the gossip contraction a GSPMD einsum).  The leafwise
     layout refuses ``observe`` — capture is defined on the concatenated
     wire buffer.  ``kernel_rng`` (None defers to
     `kernels.default_kernel_rng`, i.e. on for real TPUs) moves the
@@ -700,7 +726,13 @@ def make_decentralized_step(
                         state.tracker)
         aux = {
             "loss": losses.mean(),
-            "consensus_error": consensus_error(new_params),
+            # the stored values' spread, summed in float32, on a mesh (the
+            # one place a chip has shown the compiler handing the report
+            # an update before its rounding); every other path keeps its
+            # former sum until the one-chip cell's check numbers may move
+            # (ROADMAP D12)
+            "consensus_error": consensus_error(new_params,
+                                               stored=mesh is not None),
         }
         if alive is not None:
             aux["fault_down"] = (

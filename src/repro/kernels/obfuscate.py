@@ -71,6 +71,14 @@ def _obfuscate_math(x, g, bits, lam_bar, w_self, b_self, out_dtype):
     return (w_self * x - b_self * (lam * g)).astype(out_dtype)
 
 
+def obfuscated(g, bits, lam_bar):
+    """lambda(bits) ∘ g in float32: `_obfuscate_math`'s u, for XLA to fuse
+    where no kernel runs (the leafwise update on a mesh)."""
+    f = (bits >> 9) | jnp.uint32(0x3F800000)
+    u01 = jax.lax.bitcast_convert_type(f, jnp.float32) - 1.0
+    return ((2.0 * lam_bar) * u01) * g.astype(jnp.float32)
+
+
 def _obfuscate_kernel(x_ref, g_ref, bits_ref, scal_ref, o_ref):
     """scal_ref: (3,) = [lam_bar, w_self, b_self] in SMEM-like VMEM."""
     o_ref[...] = _obfuscate_math(x_ref[...], g_ref[...], bits_ref[...],

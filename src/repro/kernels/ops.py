@@ -17,7 +17,7 @@ from .gossip import (gossip_update, guarded_gossip_update,
                      masked_gossip_update, masked_gossip_update_krng,
                      ring_gossip_update, ring_obfuscate_gossip,
                      ring_obfuscate_gossip_krng)
-from .obfuscate import obfuscate_update, obfuscate_update_krng
+from .obfuscate import obfuscate_update, obfuscate_update_krng, obfuscated
 from .runtime import (default_interpret, default_kernel_rng,
                       default_use_pallas, resolve_kernel_rng)
 from .ssm_scan import ssd_intra_chunk
@@ -358,13 +358,12 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
       only the agent dim, so columns never interact).  This is the
       reference the property tests compare against.
     * ``mesh`` + ``leaf_specs`` (a PartitionSpec per leaf, agent dim
-      included) — the obfuscate kernel runs under `shard_map`, one
-      pallas_call per device on its LOCAL block with the per-shard
-      column count padded to the kernel grid (zero communication: the
-      kernel is elementwise), while the gossip contraction stays an
-      einsum so GSPMD emits the agent-axis collective itself and every
-      non-agent dim keeps its sharding.  ``corrupt`` is refused here —
-      the fault paths are dense-only today.
+      included) — the obfuscate kernel's math as XLA's elementwise
+      fusion on each leaf in its own sharding (zero communication), and
+      the gossip contraction as an einsum, so GSPMD emits the agent-axis
+      collective itself and every non-agent dim keeps its sharding.
+      ``corrupt`` is refused here — the fault paths are dense-only
+      today.
     """
     if mesh is None:
         return jax.tree.map(
@@ -380,25 +379,14 @@ def sharded_pdsgd_tree(W: jax.Array, B: jax.Array, x_tree: Pytree,
         raise ValueError("mesh given but leaf_specs is None; resolve "
                          "specs via dist.sharding.logical_spec")
 
-    def leaf_obfuscate(x, g, bits, spec):
-        def body(xl, gl, bl):
-            m = xl.shape[0]
-            with tr.region(tr.LAYOUT):
-                xf, pad = _pad_cols(xl.reshape(m, -1), 256)
-                gf, _ = _pad_cols(gl.reshape(m, -1), 256)
-                bf, _ = _pad_cols(bl.reshape(m, -1), 256)
-            with tr.region(tr.OBFUSCATE):
-                u = obfuscate_update(xf, gf, bf, lam_bar, jnp.float32(0.0),
-                                     jnp.float32(-1.0), interpret=interpret)
-            with tr.region(tr.LAYOUT):
-                if pad:
-                    u = u[:, :-pad]
-                return u.reshape(xl.shape).astype(xl.dtype)
-        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=False)(x, g, bits)
-
-    u_tree = jax.tree.map(leaf_obfuscate, x_tree, g_tree, bits_tree,
-                          leaf_specs)
+    # the kernel's Lambda o g as an XLA fusion on each leaf as it is
+    # sharded: the kernel needs each shard flattened to one (1, N) row, a
+    # relayout whose executable code grew with the leaf (29 MB a layer at
+    # granite widths, and the step compiled 18x slower)
+    with tr.region(tr.OBFUSCATE):
+        u_tree = jax.tree.map(
+            lambda x, g, b: obfuscated(g, b, lam_bar).astype(x.dtype),
+            x_tree, g_tree, bits_tree)
     if mask is not None:
         from ..core.mixing import metropolis_from_mask
         with tr.region(tr.STEP_MIX):

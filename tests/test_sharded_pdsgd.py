@@ -83,13 +83,13 @@ def test_leafwise_matches_concat_bitwise(m, seed, masked):
 
 
 def test_leafwise_mesh_trivial_matches_concat_bitwise():
-    """The mesh flavor (shard_map obfuscate + einsum gossip) on a
-    trivially-sharded 1-device mesh matches concat.  The obfuscate stage
-    is the same kernel, but the gossip stage is an XLA einsum over each
-    (m, ...) leaf instead of the kernel's blocked (m, m) @ (m, bn) dot:
-    XLA may order or FMA-contract the m-term sums differently, so the
-    two agree to float32 rounding (a few ulp on O(1) values), not
-    bitwise."""
+    """The mesh flavor (the kernel's Lambda o g as an XLA fusion, einsum
+    gossip) on a trivially-sharded 1-device mesh matches concat.  The
+    obfuscate stage is the kernel's math, but the gossip stage is an XLA
+    einsum over each (m, ...) leaf instead of the kernel's blocked
+    (m, m) @ (m, bn) dot: XLA may order or FMA-contract the m-term sums
+    differently, so the two agree to float32 rounding (a few ulp on O(1)
+    values), not bitwise."""
     from jax.sharding import PartitionSpec as P
     m, seed = 4, 7
     mesh = jax.make_mesh((1, 1, 1), ("data", "fsdp", "model"),
@@ -104,6 +104,24 @@ def test_leafwise_mesh_trivial_matches_concat_bitwise():
     for k in _SHAPES:
         np.testing.assert_allclose(np.asarray(out[k]), np.asarray(ref[k]),
                                    rtol=1e-6, atol=1e-6, err_msg=k)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_mesh_obfuscation_is_the_kernels_bit_for_bit(dtype):
+    """The mesh path's u = Lambda o g, rounded to the parameters' dtype,
+    is what the obfuscate kernel writes for the same bits."""
+    from repro.kernels.obfuscate import obfuscate_update, obfuscated
+    m, n = 2, 4096
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(k1, (m, n)).astype(dtype)
+    g = jax.random.normal(k2, (m, n)).astype(dtype)
+    bits = jax.random.bits(k3, (m, n), dtype=jnp.uint32)
+    lam = jnp.float32(0.05)
+    want = obfuscate_update(x, g, bits, lam, jnp.float32(0.0),
+                            jnp.float32(-1.0), interpret=True)
+    got = jax.jit(lambda g, b: obfuscated(g, b, lam).astype(dtype))(g, bits)
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(want, np.float32))
 
 
 def test_sharded_tree_mesh_needs_specs_and_refuses_corrupt():
@@ -411,3 +429,94 @@ def test_agents_times_fsdp_mesh_composition_subprocess():
     assert all(np.isfinite(l) for l in res["losses"])
     assert res["n_sharded"] > 0
     assert res["out_sharded"] == res["n_sharded"]
+
+
+_REPORT_SCRIPT = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    sys.path.insert(0, {src!r})
+    import dataclasses, json
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config, tiny_variant
+    from repro.core import (init_state, make_decentralized_step,
+                            make_topology)
+    from repro.core.mixing import as_process
+    from repro.core.schedules import warmup_harmonic
+    from repro.data import make_lm_pipeline
+    from repro.dist.sharding import TRAIN_RULES, logical_spec
+    from repro.launch.mesh import make_sharded_mesh
+    from repro.launch.specs import with_agent_axis
+    from repro.models import build_model
+    from repro.optim import shard_like
+
+    m = 2
+    mesh = make_sharded_mesh(agents=m, fsdp=2, tensor=1)
+    cfg = dataclasses.replace(tiny_variant(get_config("stablelm-3b")),
+                              d_model=64, d_ff=128, dtype="bfloat16")
+    bundle = build_model(cfg, mesh=mesh)
+    p_abs, p_log = with_agent_axis(bundle.abstract(),
+                                   bundle.logical_axes(), m)
+    leaf_specs = jax.tree.map(
+        lambda a, log: logical_spec(mesh, a.shape, log, TRAIN_RULES),
+        p_abs, p_log)
+    params_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), leaf_specs)
+    state = init_state(bundle.init(jax.random.key(0)), m)
+    state = jax.device_put(state, shard_like(
+        state, state.params, params_sh,
+        scalar_sharding=NamedSharding(mesh, P())))
+    step = make_decentralized_step(
+        bundle.loss_fn, as_process(make_topology("ring", m)),
+        warmup_harmonic(0.4, hold=200), spmd_axis_name="data",
+        kernel_layout="leafwise", mesh=mesh, leaf_specs=leaf_specs)
+    batch = make_lm_pipeline(cfg.vocab_size, m, 4, 16, seed=0).batch_at(0)
+    state, aux = step(state, batch, jax.random.key(3))
+    stored = 0.0
+    for leaf in jax.tree.leaves(state.params):
+        p = np.asarray(leaf.astype(jnp.float32))
+        mean = np.asarray(jnp.asarray(p.mean(0), leaf.dtype)
+                          .astype(jnp.float32))
+        stored += float(np.sum(np.square(p - mean), dtype=np.float64))
+    print(json.dumps({{"dtype": str(jax.tree.leaves(state.params)[0].dtype),
+                       "reported": float(aux["consensus_error"]),
+                       "stored": stored}}))
+""")
+
+
+def test_mesh_step_reports_the_consensus_of_its_stored_parameters():
+    """The consensus error a mesh step reports is the statistic of the
+    parameters it stores: each agent's bf16 values less their agent mean
+    rounded to bf16, squared and summed in float32 (the benchmark
+    reference's rule), to float32 round-off (RTOL 1e-5 over a few
+    thousand terms).  A report computed in bf16, or one the compiler may
+    feed the update before its rounding, misses it: the bf16 squares and
+    sums alone are off by ~1e-3."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    script = _REPORT_SCRIPT.format(src=os.path.abspath(src))
+    out = subprocess.run([sys.executable, "-c", script],
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["dtype"] == "bfloat16"
+    assert res["stored"] > 0
+    assert abs(res["reported"] - res["stored"]) <= 1e-5 * res["stored"]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_stored_consensus_rounds_to_the_parameter_dtype(dtype):
+    """`consensus_error(stored=True)` of a leaf held in ``dtype`` is the
+    float32 statistic of its values with the agent mean rounded to
+    ``dtype``; `_as_stored` rounds float32 to nearest even exactly as a
+    cast to ``dtype`` and back does."""
+    from repro.core.pdsgd import _as_stored, consensus_error
+    x = jax.random.normal(jax.random.key(0), (3, 257)) * 0.02
+    assert np.array_equal(np.asarray(_as_stored(x, dtype)),
+                          np.asarray(x.astype(dtype).astype(jnp.float32)))
+    p = (x + 1e-4 * jax.random.normal(jax.random.key(1), (3, 257))
+         ).astype(dtype)
+    q = np.asarray(p.astype(jnp.float32))
+    mean = np.asarray(jnp.asarray(q.mean(0), dtype).astype(jnp.float32))
+    want = float(np.sum(np.square(q - mean), dtype=np.float64))
+    got = float(consensus_error({"w": p}, stored=True))
+    assert abs(got - want) <= 1e-6 * want
