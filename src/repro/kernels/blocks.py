@@ -11,9 +11,11 @@ VMEM allows: each kernel says what one of its columns holds in VMEM, and
 The count is the pessimistic one: rows are padded to the sublane tile of
 their dtype (8 rows of 32 bits, 16 of bf16: a 4-row bf16 block counts as
 16 rows), every streamed block counts twice (the pipeline double-buffers
-it), and each float32 temporary of the body counts as an (m, bc) array of
-its own.  The kernels ask Mosaic for `VMEM_BUDGET` of scoped VMEM, so a
-block the rule admits compiles.
+it), and each float32 temporary of the body that spans the block counts as
+an (m, bc) array of its own (the dense gossip works through column slices
+of at most sixteen vregs a value, which are left out).  The kernels ask
+Mosaic for `VMEM_BUDGET` of scoped VMEM, so a block the rule admits
+compiles.
 """
 from __future__ import annotations
 
@@ -23,9 +25,10 @@ from jax.experimental.pallas import tpu as pltpu
 LANE_BLOCK = 512
 # What one kernel's blocks and temporaries may take, by the count above,
 # and the scoped VMEM the kernels compile with (a v5e core has 128 MiB).
-# At m = 4 it admits 65536 columns for both kernels: alone on a v5e, the
-# obfuscate kernel runs at 570 GB/s from 32768 columns on, and the gossip
-# kernel gains 1.7% from 32768 to 65536 columns and 0.5% more at 131072.
+# At m = 4 it admits 65536 columns for the obfuscate kernel and 131072 for
+# the dense gossip: alone on a v5e, the obfuscate kernel runs at 570 GB/s
+# from 32768 columns on, and the gossip, then two MXU dots, gained 1.7%
+# from 32768 to 65536 columns and 0.5% more at 131072.
 VMEM_BUDGET = 28 << 20
 # The interpreter runs a kernel body as XLA:CPU operations, and XLA:CPU
 # computes the gossip's (m, m) @ (m, bc) dot with another kernel, which

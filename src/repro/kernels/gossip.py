@@ -2,10 +2,23 @@
 
 X/U are (m, n) agent-stacked flattened parameters; W/B are tiny (m, m)
 mixing matrices that live in VMEM for the whole kernel.  The grid tiles n;
-each program does two (m x m) @ (m x bn) MXU matmuls and one subtract —
-fusing the subtraction halves output traffic vs two separate einsums.
-m <= 32 here, so the matmuls are m-padded to the 128-lane MXU; the win is
-traffic, not FLOPs (gossip is memory-bound).
+each program contracts the m agents of one (m, bn) column block and
+subtracts, fusing what two einsums would write twice.  W and B are never
+rounded below float32: a bf16 W's rows no longer sum to 1.
+
+The contraction runs on the VPU: for each source agent j, x[j] and u[j]
+are widened to float32 once, and ``w[:, j] * x[j]`` and ``b[:, j] * u[j]``
+are accumulated in float32, 2 m^2 multiply-adds a column -- a float32
+dot's arithmetic term for term.  The calls' names end in ``_vpu``
+(``_gossip_update_vpu``), which the compiled step's custom calls keep.
+It replaced two ``HIGHEST`` (m, m) @ (m, bn) MXU dots, six bf16 passes
+each over K = m of the array's 128 rows: on a v5e the granite update's
+gossip took 29.0 ms a step with them against 8.5 ms for the same bytes
+with no contraction at all.  The dots cost about the same a column at any
+m up to 128, the VPU's 2 m^2 multiply-adds grow with m: compiled for a
+v5e, the VPU form's bundles put it ahead up to about 8 agents and behind
+the dots from about 16 on, more agents than any launcher here runs on
+these kernels.
 
 `masked_gossip_update` is the time-varying variant for
 `core.mixing.MixingProcess`: it takes the step's realized EDGE MASK
@@ -13,7 +26,7 @@ instead of a pre-built W_k and performs mask -> Metropolis re-weight ->
 W_k @ X - B @ U inside one pallas_call.  W_k never exists in HBM — the
 (m, m) mask is the only per-step mixing input staged, and the re-weighting
 (two tiny reductions + a divide on an (m, m) VMEM tile) is free next to
-the matmuls.  The formula mirrors `core.mixing.metropolis_from_mask`
+the contraction.  The formula mirrors `core.mixing.metropolis_from_mask`
 exactly; keep the two in sync.
 
 `ring_gossip_update` / `ring_obfuscate_gossip` are the RING-SCHEDULED
@@ -45,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .blocks import COMPILER_PARAMS, column_block
+from .blocks import COMPILER_PARAMS, LANE_BLOCK, column_block, vmem_rows
 from .obfuscate import tile_seed
 from .runtime import resolve_interpret
 
@@ -53,25 +66,41 @@ from .runtime import resolve_interpret
 # their own); the dense kernels take theirs from `gossip_block`.
 DEFAULT_BLOCK_N = 512
 # f32 matmuls at full precision: on the TPU's MXU the default rounds f32
-# operands to bf16, which would round the mixing weights (W's rows then no
-# longer sum to 1) and every staged v_j the 0/1 shift matmul moves.  The
-# (m, m) operands make the extra MXU passes free next to the HBM traffic.
+# operands to bf16, which would round every staged v_j the ring kernels'
+# 0/1 shift matmul moves.  The six passes are not free (the dense kernels
+# left them for the VPU); the shift matmuls still pay them.
 _EXACT = jax.lax.Precision.HIGHEST
+# The dense kernels contract a block one slice of columns at a time, so that
+# their float32 values stay in vector registers rather than in (m, bn) VMEM
+# temporaries: each (m, C) float32 value spans at most this many elements,
+# sixteen (8, 128) vregs.  In the kernel compiled for a v5e, a wider slice
+# pays each loop iteration's fixed latency over more columns until its
+# values spill: at 2 to 8 agents 2048 columns take 23-26% fewer bundles a
+# column than 512, at 16 agents 1024 and at 32 agents 512 take the fewest.
+_VPU_ELEMENTS = 16 * 8 * 128
+
+
+def _vpu_columns(m: int) -> int:
+    """The columns of one slice of the dense kernels' contraction: a
+    multiple of `LANE_BLOCK`, whose (m, C) float32 values, rows padded
+    to the sublane tile, hold at most `_VPU_ELEMENTS`."""
+    c = _VPU_ELEMENTS // vmem_rows(m, 4) // LANE_BLOCK * LANE_BLOCK
+    return max(LANE_BLOCK, c)
 
 
 def gossip_block(m: int, width: int, dtype, guarded: bool = False,
                  interpret: bool = False) -> int:
     """Column block of the dense gossip kernels over (m, width) buffers
     of ``dtype``.  The plain and masked kernels stream X, U and x' and
-    hold five float32 (m, bn) temporaries (x, u widened, the two
-    products, the result).  The guarded kernel also streams the transmit
-    buffers and holds (m, m, bn) per-link tensors, m (m, bn) temporaries
-    each (the two products, their difference, the guard's select), so
-    its block narrows as m grows."""
+    hold no float32 (m, bn) temporaries (they work through
+    `_vpu_columns` at a time).  The guarded kernel also streams the
+    transmit buffers and holds (m, m, bn) per-link tensors, m (m, bn)
+    temporaries each (the two products, their difference, the guard's
+    select), so its block narrows as m grows."""
     s = jnp.dtype(dtype).itemsize
     if guarded:
         return column_block(m, width, (s,) * 5, 4 * m + 5, interpret)
-    return column_block(m, width, (s,) * 3, 5, interpret)
+    return column_block(m, width, (s,) * 3, 0, interpret)
 
 
 def _block_n(block_n, X, interpret, guarded=False):
@@ -82,14 +111,40 @@ def _block_n(block_n, X, interpret, guarded=False):
     return min(block_n or gossip_block(m, n, X.dtype, guarded, interpret), n)
 
 
+def _mix(w, b, x_ref, u_ref, o_ref):
+    """o = w @ x - b @ u over the agent rows of one (m, bn) column block,
+    in float32 with the (m, m) float32 ``w`` and ``b`` as they are."""
+    m, bn = x_ref.shape
+    width = _vpu_columns(m)
+
+    def columns(cols):
+        # the dots' sums, term by term and in their order: one per
+        # operand, then their difference.  Product first: XLA:CPU then
+        # contracts each step into the FMA its own dot uses, so the
+        # interpreted kernel agrees bit for bit with the einsum paths
+        # (for m <= 8; above, XLA:CPU's dot sums in another order).
+        x = x_ref[:, cols].astype(jnp.float32)
+        u = u_ref[:, cols].astype(jnp.float32)
+        mixed, desc = w[:, 0:1] * x[0:1], b[:, 0:1] * u[0:1]
+        for j in range(1, m):
+            mixed = w[:, j:j + 1] * x[j:j + 1] + mixed
+            desc = b[:, j:j + 1] * u[j:j + 1] + desc
+        o_ref[:, cols] = (mixed - desc).astype(o_ref.dtype)
+
+    def step(c, carry):
+        columns(pl.ds(pl.multiple_of(c * width, width), width))
+        return carry
+
+    full = bn // width
+    if full:
+        jax.lax.fori_loop(0, full, step, 0)
+    if bn % width:  # a narrow block ends in part of a slice
+        columns(slice(full * width, bn))
+
+
 def _gossip_kernel(w_ref, b_ref, x_ref, u_ref, o_ref):
-    w = w_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)
-    mixed = jnp.dot(w, x, precision=_EXACT, preferred_element_type=jnp.float32)
-    desc = jnp.dot(b, u, precision=_EXACT, preferred_element_type=jnp.float32)
-    o_ref[...] = (mixed - desc).astype(o_ref.dtype)
+    _mix(w_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
+         x_ref, u_ref, o_ref)
 
 
 def gossip_update(W: jax.Array, B: jax.Array, X: jax.Array, U: jax.Array,
@@ -108,6 +163,7 @@ def _gossip_update(W, B, X, U, block_n, interpret):
     bn = _block_n(block_n, X, interpret)
     return pl.pallas_call(
         _gossip_kernel,
+        name="_gossip_update_vpu",
         grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((m, m), lambda i: (0, 0)),
@@ -153,14 +209,8 @@ def _mask_from_bits(bits, keep_prob, adj):
 
 
 def _masked_gossip_kernel(mask_ref, b_ref, x_ref, u_ref, o_ref):
-    mask = mask_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)
-    w = _metropolis_weights(mask)
-    mixed = jnp.dot(w, x, precision=_EXACT, preferred_element_type=jnp.float32)
-    desc = jnp.dot(b, u, precision=_EXACT, preferred_element_type=jnp.float32)
-    o_ref[...] = (mixed - desc).astype(o_ref.dtype)
+    w = _metropolis_weights(mask_ref[...].astype(jnp.float32))
+    _mix(w, b_ref[...].astype(jnp.float32), x_ref, u_ref, o_ref)
 
 
 def masked_gossip_update(mask: jax.Array, B: jax.Array, X: jax.Array,
@@ -181,6 +231,7 @@ def _masked_gossip_update(mask, B, X, U, block_n, interpret):
     bn = _block_n(block_n, X, interpret)
     return pl.pallas_call(
         _masked_gossip_kernel,
+        name="_masked_gossip_update_vpu",
         grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((m, m), lambda i: (0, 0)),
@@ -216,13 +267,8 @@ def _masked_gossip_krng_kernel(seed_ref, prob_ref, adj_ref, b_ref, x_ref,
     mask = _mask_from_bits(bits, prob_ref[0],
                            adj_ref[...].astype(jnp.float32))
     mask_ref[...] = mask
-    b = b_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)
-    w = _metropolis_weights(mask)
-    mixed = jnp.dot(w, x, precision=_EXACT, preferred_element_type=jnp.float32)
-    desc = jnp.dot(b, u, precision=_EXACT, preferred_element_type=jnp.float32)
-    o_ref[...] = (mixed - desc).astype(o_ref.dtype)
+    _mix(_metropolis_weights(mask), b_ref[...].astype(jnp.float32),
+         x_ref, u_ref, o_ref)
 
 
 def masked_gossip_update_krng(seed: jax.Array, keep_prob, adj: jax.Array,
@@ -258,6 +304,7 @@ def _masked_gossip_update_krng(seed, keep_prob, adj, B, X, U, block_n,
     prob = jnp.asarray(keep_prob, jnp.float32).reshape(1)
     return pl.pallas_call(
         _masked_gossip_krng_kernel,
+        name="_masked_gossip_update_krng_vpu",
         grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),

@@ -91,11 +91,26 @@ def test_obfuscate_update_krng_compiles(one_chip):
         x, x, seed, s)
 
 
-def test_gossip_update_compiles(one_chip):
-    mat = _sds(one_chip, (M, M), jnp.float32)
-    x = _sds(one_chip, (M, D), jnp.bfloat16)
-    _compile(lambda w, b, x, u: _gossip_update(
+def _gossip_call(hlo):
+    """The name of the compiled program's one gossip custom call."""
+    names = [line.split("=", 1)[0].split("%")[-1].split(".")[0]
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 1, names
+    return names[0]
+
+
+# the granite cell's 4 agents, and 32, contract on the VPU
+GOSSIP_FORMS = [(M, "_gossip_update_vpu"), (32, "_gossip_update_vpu")]
+
+
+@pytest.mark.parametrize("m,form", GOSSIP_FORMS)
+def test_gossip_update_compiles(one_chip, m, form):
+    mat = _sds(one_chip, (m, m), jnp.float32)
+    x = _sds(one_chip, (m, D * M // m), jnp.bfloat16)
+    hlo = _compile(lambda w, b, x, u: _gossip_update(
         w, b, x, u, block_n=512, interpret=False), mat, mat, x, x)
+    assert _gossip_call(hlo) == form
 
 
 def test_masked_gossip_update_krng_compiles(one_chip):
@@ -145,11 +160,15 @@ def test_obfuscate_update_compiles_granite(one_chip):
         x, x, bits, _scalar(one_chip))
 
 
-def test_gossip_update_compiles_granite(one_chip):
-    mat = _sds(one_chip, (M, M), jnp.float32)
-    x = _sds(one_chip, (M, GRANITE), jnp.bfloat16)
-    _compile(lambda w, b, x, u: _gossip_update(
+@pytest.mark.parametrize("m,form", GOSSIP_FORMS)
+def test_gossip_update_compiles_granite(one_chip, m, form):
+    """The granite update's gossip with the rule's block, at its 4 agents
+    and over as many bytes at 32."""
+    mat = _sds(one_chip, (m, m), jnp.float32)
+    x = _sds(one_chip, (m, GRANITE * M // m), jnp.bfloat16)
+    hlo = _compile(lambda w, b, x, u: _gossip_update(
         w, b, x, u, block_n=None, interpret=False), mat, mat, x, x)
+    assert _gossip_call(hlo) == form
 
 
 def test_masked_gossip_update_krng_compiles_granite(one_chip):

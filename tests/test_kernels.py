@@ -101,7 +101,7 @@ def test_tree_wrappers_match_core_update():
 # guarded gossip, whose per-link tensors grow with m.
 _KERNEL_COLUMNS = {
     "obfuscate": lambda m: ((2, 2, 4, 2), 5),
-    "gossip": lambda m: ((2, 2, 2), 5),
+    "gossip": lambda m: ((2, 2, 2), 0),
     "guarded": lambda m: ((2,) * 5, 4 * m + 5),
 }
 
@@ -143,7 +143,8 @@ def test_column_block_guarded_narrows_with_agents():
     assert gossip_block(32, n, bf16, guarded=True) < gossip_block(32, n, bf16)
     guarded = [gossip_block(m, n, bf16, guarded=True) for m in (2, 8, 32)]
     assert guarded == sorted(guarded, reverse=True) and guarded[0] > guarded[2]
-    assert obfuscate_block(4, n, bf16, bf16) == gossip_block(4, n, bf16)
+    # the VPU gossip holds no float32 (m, bn) temporaries: twice as wide
+    assert gossip_block(4, n, bf16) == 2 * obfuscate_block(4, n, bf16, bf16)
     assert gossip_block(4, n, bf16) >= 32768
 
 
@@ -223,9 +224,145 @@ def test_update_kernels_block_invariant_tpu():
               gossip_update(W, W, x, u, block_n=512))
     wide = (obfuscate_update(x, u, bits, 0.05, 0.0, -1.0),
             gossip_update(W, W, x, u))
-    assert gossip_block(m, n, jnp.bfloat16) == 65536
+    assert gossip_block(m, n, jnp.bfloat16) == 131072
     for a, b in zip(narrow, wide):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the dense gossip's contraction on the VPU (kernels.gossip._mix) ------
+
+_FORM_AGENTS = [2, 3, 4, 5, 8, 16, 17, 32]
+
+
+def _ring_mask(m):
+    a = np.zeros((m, m), np.float32)
+    for i in range(m):
+        a[i, (i + 1) % m] = a[(i + 1) % m, i] = 1.0
+    return jnp.asarray(a)
+
+
+def _within_bf16_ulp(out, expect):
+    """|out - expect| is at most one bf16 unit in the last place of
+    ``expect``, plus 2**-20 for a sum that cancels: there the two float32
+    sums, added in different orders, differ by more than ``expect``'s
+    own ulp."""
+    out, expect = (np.asarray(a, np.float32) for a in (out, expect))
+    mag = np.maximum(np.abs(expect), np.float32(2.0 ** -126))
+    ulp = np.ldexp(np.float32(1.0), np.floor(np.log2(mag)).astype(int) - 7)
+    return np.abs(out - expect) <= ulp + 2.0 ** -20
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("m", _FORM_AGENTS)
+def test_dense_gossip_forms_match_oracle(m, masked):
+    """The plain and masked kernels, from 2 to 32 agents, against the
+    HIGHEST oracle: a grid whose last block overhangs n and a narrow
+    buffer's one block (700 columns: one 512-column slice and part of
+    one), float32 to float32 rounding and bf16 within one bf16 ulp."""
+    from repro.core.mixing import metropolis_from_mask
+    from repro.kernels import masked_gossip_update
+    B = jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32))
+    mask = _ring_mask(m)
+    W = (metropolis_from_mask(mask) if masked else
+         jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32)))
+    for n, bn in ((2 * 1024 + 300, 1024), (700, None)):
+        for dtype in (jnp.float32, jnp.bfloat16):
+            X, U = _randn((m, n), dtype), _randn((m, n), dtype)
+            out = (masked_gossip_update(mask, B, X, U, block_n=bn)
+                   if masked else gossip_update(W, B, X, U, block_n=bn))
+            expect = ref.gossip_ref(W, B, X, U)
+            assert out.dtype == dtype and out.shape == (m, n)
+            if dtype == jnp.float32:
+                np.testing.assert_allclose(np.asarray(out),
+                                           np.asarray(expect),
+                                           rtol=1e-6, atol=1e-6)
+            else:
+                assert _within_bf16_ulp(out, expect).all(), (n, bn)
+
+
+def _consensus_rows(m, n, seed=0):
+    """float32 rows all equal to 3y, y bf16 numbers: with ring weights
+    of 1/3, every product w x rounds to y exactly and every partial sum
+    is exact, in any order."""
+    y = jax.random.normal(jax.random.key(seed), (n,)).astype(jnp.bfloat16)
+    return jnp.broadcast_to(3.0 * y.astype(jnp.float32), (m, n))
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("m", [3, 4, 5, 17])
+def test_gossip_keeps_consensus_exactly(m, masked):
+    """Equal rows, U = 0 and ring Metropolis weights (1/3, no bf16
+    number): x' is x bit for bit.  The same kernel with
+    W rounded to bf16 -- what a one-pass MXU dot does -- does not: its
+    rows sum to 1 + 2**-9."""
+    from repro.core.mixing import metropolis_from_mask
+    from repro.kernels import masked_gossip_update
+    mask = _ring_mask(m)
+    W = metropolis_from_mask(mask)
+    X = _consensus_rows(m, 1024)
+    U = jnp.zeros_like(X)
+    B = jnp.full((m, m), 0.25, jnp.float32)
+    out = (masked_gossip_update(mask, B, X, U, block_n=512) if masked
+           else gossip_update(W, B, X, U, block_n=512))
+    assert np.array_equal(np.asarray(out), np.asarray(X))
+    rounded = W.astype(jnp.bfloat16).astype(jnp.float32)
+    assert not np.array_equal(
+        np.asarray(gossip_update(rounded, B, X, U, block_n=512)),
+        np.asarray(X))
+
+
+def _pallas_names(jaxpr):
+    """The ``name`` of every pallas_call in a jaxpr, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"]
+            continue
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                yield from _pallas_names(inner)
+
+
+def test_gossip_calls_name_their_form():
+    """The plain and masked kernels' calls say that they contract on the
+    VPU, at the granite cell's 4 agents and at 32, and the compiled
+    step's custom call keeps the name."""
+    from repro.kernels import masked_gossip_update
+    for m in (4, 32):
+        eye, x = jnp.eye(m), jnp.ones((m, 512), jnp.bfloat16)
+        plain = jax.make_jaxpr(lambda w, x: gossip_update(w, w, x, x))
+        masked = jax.make_jaxpr(
+            lambda w, x: masked_gossip_update(w, w, x, x))
+        assert list(_pallas_names(plain(eye, x).jaxpr)) == [
+            "_gossip_update_vpu"]
+        assert list(_pallas_names(masked(eye, x).jaxpr)) == [
+            "_masked_gossip_update_vpu"]
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="the compiled kernels need the chip")
+def test_vpu_gossip_matches_highest_oracle_tpu():
+    """Compiled at m = 4 over granite-width blocks (the last one
+    overhanging n): the VPU form is within one bf16 ulp of XLA's HIGHEST
+    einsum, and keeps float32 consensus rows bit for bit where a
+    bf16-rounded W does not."""
+    from repro.core.mixing import metropolis_from_mask
+    from repro.kernels.gossip import gossip_block
+    m, n = 4, 3 * 65536 + 7 * 512
+    assert gossip_block(m, n, jnp.bfloat16) >= 65536
+    X, U = _randn((m, n), jnp.bfloat16), _randn((m, n), jnp.bfloat16)
+    W = jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32))
+    B = jnp.asarray(RNG.dirichlet(np.ones(m), m).T.astype(np.float32))
+    out = gossip_update(W, B, X, U)
+    assert _within_bf16_ulp(out, ref.gossip_ref(W, B, X, U)).all()
+    W = metropolis_from_mask(_ring_mask(m))
+    X = _consensus_rows(m, n)
+    U = jnp.zeros_like(X)
+    assert np.array_equal(np.asarray(gossip_update(W, B, X, U)),
+                          np.asarray(X))
+    rounded = W.astype(jnp.bfloat16).astype(jnp.float32)
+    assert not np.array_equal(np.asarray(gossip_update(rounded, B, X, U)),
+                              np.asarray(X))
 
 
 # -- in-kernel TPU randomness (the kernels.runtime knob) ------------------
